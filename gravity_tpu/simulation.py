@@ -28,6 +28,7 @@ from .ops.forces import accelerations_vs, pairwise_accelerations_chunked
 from .ops.integrators import FORCE_EVALS_PER_STEP, init_carry, make_step_fn
 from .ops import diagnostics
 from .state import ParticleState
+from .telemetry import tracing as _tracing
 from .utils import faults as _faults
 from .utils.logging import RunLogger
 from .utils.timing import (
@@ -1750,7 +1751,7 @@ class Simulator:
         ``submit_save`` saves inline (the serial path). With a
         telemetry bundle attached, every checkpoint save emits a
         ``checkpoint`` span (timed where it RUNS — on the background
-        thread under the pipeline)."""
+        thread under the pipeline); the profiler sees it either way."""
         host_writer = None
         if enabled and (
             trajectory_writer is not None or checkpoint_manager is not None
@@ -1769,13 +1770,11 @@ class Simulator:
         def _save(at_step, at_state, extra=None):
             from .utils.checkpoint import save_checkpoint
 
-            t0 = _time.time()
-            save_checkpoint(
-                checkpoint_manager, at_step, at_state, extra=extra
-            )
-            if tracer is not None and trace_id is not None:
-                tracer.emit("checkpoint", trace_id, t0,
-                            _time.time() - t0, step=at_step)
+            with _tracing.span("checkpoint", tracer, trace_id,
+                               step=at_step):
+                save_checkpoint(
+                    checkpoint_manager, at_step, at_state, extra=extra
+                )
 
         def submit_save(at_step, at_state, extra=None):
             # The background writer runs the SHA-256 payload checksum
@@ -1831,7 +1830,10 @@ class Simulator:
         stack's span structure — per-block ``block`` spans,
         ``checkpoint`` spans, and flight-recorder dumps on divergence
         and preemption (docs/observability.md). Adaptive runs take the
-        supervisor's recorder triggers only.
+        supervisor's recorder triggers only. The driver's phases are
+        spans (``run``, ``run.open``, ``block.dispatch``/``wait``/
+        ``consume``, ``run.drain``, ``run.close``) on the profiler's
+        clock whether or not ``telemetry`` is given.
 
         ``config.adaptive`` runs dispatch to :meth:`run_adaptive` — the
         CLI did this already, but a Python-API caller setting
@@ -1842,12 +1844,15 @@ class Simulator:
         through the same checkpoint-and-exit path as Ctrl-C, so
         preempted runs are resumable.
         """
-        with preemption_guard():
+        tracer = trace_id = None
+        if telemetry is not None:
+            tracer, trace_id = telemetry.tracer, _tracing.new_trace_id()
+        with preemption_guard(), _tracing.span("run", tracer, trace_id):
             return self._run_impl(
                 logger, steps=steps, trajectory_writer=trajectory_writer,
                 checkpoint_manager=checkpoint_manager,
                 metrics_logger=metrics_logger, start_step=start_step,
-                telemetry=telemetry,
+                telemetry=telemetry, trace_id=trace_id,
             )
 
     def _run_impl(
@@ -1860,6 +1865,7 @@ class Simulator:
         metrics_logger=None,
         start_step: int = 0,
         telemetry=None,
+        trace_id: Optional[str] = None,
     ) -> dict:
         config = self.config
         if config.adaptive:
@@ -1898,11 +1904,6 @@ class Simulator:
         self.io_pipelined = pipelined
         self.donated = pipelined and donation_supported()
         tracer = telemetry.tracer if telemetry is not None else None
-        trace_id = None
-        if telemetry is not None:
-            from .telemetry import tracing as _tracing
-
-            trace_id = _tracing.new_trace_id()
         host_writer, trajectory_writer, _save_cadence = (
             self._make_host_pipeline(
                 trajectory_writer, checkpoint_manager, pipelined,
@@ -1914,8 +1915,6 @@ class Simulator:
 
         from .utils.timing import HostGapTimer, pairs_metric_name
 
-        state = self.state
-        acc = init_carry(self.accel_fn, state)
         self._e0 = None
         # Numerics observatory (docs/observability.md "Numerics"):
         # the conservation ledger's t0 baseline, the sentinel cadence
@@ -1932,8 +1931,23 @@ class Simulator:
         ledger_blocks = 0
         sent_stats = {"probes": 0, "max_rel_err": None, "last": None}
         blocks_dispatched = 0
-        if ledger_on:
-            ledger0 = self._ledger_convert(self._ledger_fn(state))
+        state = self.state
+        # The state at `step`, readable by emergency saves.
+        last_good = state
+        with _tracing.span("run.open", tracer, trace_id):
+            acc = init_carry(self.accel_fn, state)
+            if ledger_on:
+                ledger0 = self._ledger_convert(self._ledger_fn(state))
+            if pipelined:
+                # Never donate the caller-visible initial state: jax
+                # marks donated arrays deleted on EVERY platform,
+                # Simulator accepts prebuilt states whose buffers the
+                # caller still owns (and same-dtype astype aliases
+                # them), and self.state must stay readable if an error
+                # fires before the first consume (the supervisor's
+                # transient resume reads it). The first dispatch
+                # consumes a private copy instead.
+                state = self._snapshot_fn(state)
         timer = StepTimer()
         timer.start()
         gap = HostGapTimer()
@@ -1951,21 +1965,14 @@ class Simulator:
         # re-integrates it).
         self._last_step = step
         run_block = self._run_block_donated if pipelined else self._run_block
-        # The state at `step`, readable by emergency saves.
-        last_good = state
-        if pipelined:
-            # Never donate the caller-visible initial state: jax marks
-            # donated arrays deleted on EVERY platform, Simulator
-            # accepts prebuilt states whose buffers the caller still
-            # owns (and same-dtype astype aliases them), and self.state
-            # must stay readable if an error fires before the first
-            # consume (the supervisor's transient resume reads it). The
-            # first dispatch consumes a private copy instead.
-            state = self._snapshot_fn(state)
         pending = None  # pipelined: dispatched block awaiting consumption
+        # The open `block.consume` span: a block's consume phase runs
+        # from its completion fence to the next dispatch.
+        consume = contextlib.ExitStack()
 
         try:
           while step < total_steps or pending is not None:
+            consume.close()
             if step < total_steps:
                 # Injected transient device errors surface at block start
                 # (utils/faults.py); the supervisor retries them with
@@ -1981,39 +1988,45 @@ class Simulator:
                     n_steps = min(block, remaining)
                     do_record = False
                 gap.dispatched()
+                dispatch = _tracing.span(
+                    "block.dispatch", tracer, trace_id, step=step,
+                    steps=n_steps,
+                )
                 if pipelined:
                     # JAX async dispatch: these return futures; block
                     # k+1 runs on device while the host consumes k.
                     # Companions on the outputs (watchdog verdict +
                     # non-aliased snapshot) are dispatched NOW, before
                     # the next iteration donates new_state.
-                    new_state, acc, traj = run_block(
-                        state, acc, n_steps=n_steps, record=do_record,
-                        record_every=every if do_record else 1,
-                    )
-                    finite = (
-                        self._finite_fn(new_state)
-                        if config.nan_check else None
-                    )
-                    snap = self._snapshot_fn(new_state)
-                    # Observatory companions ride the same async
-                    # dispatch as the finiteness verdict: their values
-                    # are fetched at consume time through the block's
-                    # own fence, so the ledger/sentinel can never
-                    # re-serialize the pipeline (the --metrics-energy
-                    # fix).
-                    led = (
-                        self._ledger_fn(new_state) if ledger_on
-                        else None
-                    )
-                    sent = (
-                        self._sentinel_fn(
-                            new_state.positions, new_state.masses
+                    with dispatch:
+                        new_state, acc, traj = run_block(
+                            state, acc, n_steps=n_steps,
+                            record=do_record,
+                            record_every=every if do_record else 1,
                         )
-                        if sent_every
-                        and blocks_dispatched % sent_every == 0
-                        else None
-                    )
+                        finite = (
+                            self._finite_fn(new_state)
+                            if config.nan_check else None
+                        )
+                        snap = self._snapshot_fn(new_state)
+                        # Observatory companions ride the same async
+                        # dispatch as the finiteness verdict: their
+                        # values are fetched at consume time through
+                        # the block's own fence, so the ledger/sentinel
+                        # can never re-serialize the pipeline (the
+                        # --metrics-energy fix).
+                        led = (
+                            self._ledger_fn(new_state) if ledger_on
+                            else None
+                        )
+                        sent = (
+                            self._sentinel_fn(
+                                new_state.positions, new_state.masses
+                            )
+                            if sent_every
+                            and blocks_dispatched % sent_every == 0
+                            else None
+                        )
                     blocks_dispatched += 1
                     state = new_state
                     step += n_steps
@@ -2026,12 +2039,19 @@ class Simulator:
                         # to consume until the second dispatch
                 else:
                     prev_state = state
-                    state, acc, traj = run_block(
-                        state, acc, n_steps=n_steps, record=do_record,
-                        record_every=every if do_record else 1,
-                    )
-                    sync(state.positions)
+                    with dispatch:
+                        state, acc, traj = run_block(
+                            state, acc, n_steps=n_steps,
+                            record=do_record,
+                            record_every=every if do_record else 1,
+                        )
+                    with _tracing.span("block.wait", tracer, trace_id,
+                                       step=step):
+                        sync(state.positions)
                     gap.completed()
+                    consume.enter_context(_tracing.span(
+                        "block.consume", tracer, trace_id, step=step,
+                    ))
                     # Injected divergence (utils/faults.py): NaN the
                     # state so the watchdog below trips through its REAL
                     # detection path.
@@ -2070,11 +2090,16 @@ class Simulator:
                 # watchdog is on, a scalar fence on the snapshot
                 # otherwise. This is where the one-block watchdog lag
                 # lives: block k's verdict is read while k+1 computes.
-                if finite is not None:
-                    finite_ok = bool(finite)
-                else:
-                    sync(bstate.positions)
+                with _tracing.span("block.wait", tracer, trace_id,
+                                   step=prev_step):
+                    if finite is not None:
+                        finite_ok = bool(finite)
+                    else:
+                        sync(bstate.positions)
                 gap.completed()
+                consume.enter_context(_tracing.span(
+                    "block.consume", tracer, trace_id, step=prev_step,
+                ))
                 if _faults.active() is not None:
                     # Injected divergence under the pipeline: the fault
                     # fires on the consumed snapshot (the forward state
@@ -2355,11 +2380,16 @@ class Simulator:
                     end_step, self.backend,
                     sent_summary["p90_rel_err"], config.error_budget,
                 )
-          # Normal completion: drain the background writer INSIDE the
-          # try so a failed trajectory/checkpoint write fails the run
-          # instead of vanishing with the thread.
-          if host_writer is not None:
-            host_writer.barrier()
+          consume.close()
+          # Normal completion: drain the background writer and close
+          # the trajectory INSIDE the try so a failed trajectory/
+          # checkpoint write fails the run instead of vanishing with
+          # the thread.
+          with _tracing.span("run.drain", tracer, trace_id):
+            if host_writer is not None:
+                host_writer.barrier()
+            if trajectory_writer is not None:
+                trajectory_writer.close()
         except KeyboardInterrupt as e:
             # Graceful interrupt OR preemption (SimulationPreempted is a
             # KeyboardInterrupt subclass): persist what we have so
@@ -2410,99 +2440,98 @@ class Simulator:
                         )
             raise
         finally:
+            consume.close()
             if host_writer is not None:
                 # Error paths land here with an exception already
                 # propagating: drain and stop the thread without
                 # raising over it (barrier above covers success).
                 host_writer.close(raise_errors=False)
-        timer.mark()
+        with _tracing.span("run.close", tracer, trace_id):
+            timer.mark()
 
-        self.state = state
-        total_time = timer.total
-        evals = FORCE_EVALS_PER_STEP[config.integrator]
-        stats = throughput(
-            self.n_real,
-            total_steps - start_step,
-            total_time,
-            num_devices=self.mesh.size if self.mesh else 1,
-            force_evals_per_step=evals,
-        )
-        if trajectory_writer is not None:
-            trajectory_writer.close()
-        # Close the gap accounting AFTER the final trajectory flush: the
-        # tail-end host work (last block's writes, manifest, writer
-        # drain) is device-idle time too.
-        gap.finish()
-        stats["io_pipeline"] = "on" if pipelined else "off"
-        stats["donated"] = bool(self.donated)
-        # Routing observability (docs/scaling.md "Autotuned routing"):
-        # which backend actually ran, whether the autotune cache hit,
-        # and what the probe cost — the run-stats half of the
-        # acceptance contract (the BENCH JSON line carries the same).
-        stats["backend"] = self.backend
-        stats["autotune_cache"] = self.autotune["cache"]
-        stats["autotune_probe_ms"] = self.autotune["probe_ms"]
-        stats["host_gap_frac"] = gap.host_gap_frac
-        self.last_host_gap_frac = gap.host_gap_frac
-        # Performance observatory (docs/observability.md
-        # "Performance"): the perf facts promoted into the run's
-        # metrics registry when a telemetry bundle is attached — the
-        # same gauge names the serving worker publishes, so solo and
-        # served runs merge in one fleet view. The run's own
-        # compiled-program rows ride along in stats["perf"].
-        if telemetry is not None:
-            from .telemetry import declare_worker_metrics
+            self.state = state
+            total_time = timer.total
+            evals = FORCE_EVALS_PER_STEP[config.integrator]
+            stats = throughput(
+                self.n_real,
+                total_steps - start_step,
+                total_time,
+                num_devices=self.mesh.size if self.mesh else 1,
+                force_evals_per_step=evals,
+            )
+            # Close the gap accounting AFTER the final trajectory flush: the
+            # tail-end host work (last block's writes, manifest, writer
+            # drain) is device-idle time too.
+            gap.finish()
+            stats["io_pipeline"] = "on" if pipelined else "off"
+            stats["donated"] = bool(self.donated)
+            # Routing observability (docs/scaling.md "Autotuned routing"):
+            # which backend actually ran, whether the autotune cache hit,
+            # and what the probe cost — the run-stats half of the
+            # acceptance contract (the BENCH JSON line carries the same).
+            stats["backend"] = self.backend
+            stats["autotune_cache"] = self.autotune["cache"]
+            stats["autotune_probe_ms"] = self.autotune["probe_ms"]
+            stats["host_gap_frac"] = gap.host_gap_frac
+            # Performance observatory (docs/observability.md
+            # "Performance"): the perf facts promoted into the run's
+            # metrics registry when a telemetry bundle is attached — the
+            # same gauge names the serving worker publishes, so solo and
+            # served runs merge in one fleet view. The run's own
+            # compiled-program rows ride along in stats["perf"].
+            if telemetry is not None:
+                from .telemetry import declare_worker_metrics
 
-            reg = declare_worker_metrics(telemetry.registry)
-            if gap.host_gap_frac is not None:
-                reg.gauge("gravity_host_gap_frac").set(
-                    gap.host_gap_frac
-                )
-            if total_time > 0:
-                reg.gauge("gravity_steps_per_sec").set(
-                    (total_steps - start_step) / total_time
-                )
-            if self.autotune["probe_ms"]:
-                reg.histogram("gravity_autotune_probe_ms").observe(
-                    self.autotune["probe_ms"]
-                )
-        from .telemetry import perf as _perf
+                reg = declare_worker_metrics(telemetry.registry)
+                if gap.host_gap_frac is not None:
+                    reg.gauge("gravity_host_gap_frac").set(
+                        gap.host_gap_frac
+                    )
+                if total_time > 0:
+                    reg.gauge("gravity_steps_per_sec").set(
+                        (total_steps - start_step) / total_time
+                    )
+                if self.autotune["probe_ms"]:
+                    reg.histogram("gravity_autotune_probe_ms").observe(
+                        self.autotune["probe_ms"]
+                    )
+            from .telemetry import perf as _perf
 
-        stats["perf"] = _perf.summarize_rows([
-            r for r in _perf.ledger().rows_list()
-            if r.get("key") == self._run_block.key
-        ])
-        if ledger_on:
-            # The drift series' run-level summary (docs/observability
-            # .md "Numerics") — consumed by the BENCH JSON line and the
-            # cadence A/B alongside host_gap_frac.
-            stats["ledger"] = {
-                "blocks": ledger_blocks,
-                "max_energy_drift": max_energy_drift,
-                **(drift_last or {}),
-            }
-            if ledger_last is not None \
-                    and ledger_last["energy"] is not None:
-                stats["total_energy"] = float(ledger_last["energy"])
-        if sent_every:
-            stats["sentinel"] = {
-                "backend": self.backend,
-                "every": sent_every,
-                "k": int(self.config.sentinel_k),
-                "probes": sent_stats["probes"],
-                "max_rel_err": sent_stats["max_rel_err"],
-                **{
-                    k: sent_stats["last"][k]
-                    for k in ("median_rel_err", "p90_rel_err")
-                    if sent_stats["last"] is not None
-                },
-            }
-        if trace_id is not None:
-            stats["trace_id"] = trace_id
-        if config.merge_radius > 0.0:
-            stats["merged_pairs"] = merged_total
-        return self._finish(logger, total_time, total_steps - start_step,
-                            stats)
+            stats["perf"] = _perf.summarize_rows([
+                r for r in _perf.ledger().rows_list()
+                if r.get("key") == self._run_block.key
+            ])
+            if ledger_on:
+                # The drift series' run-level summary (docs/observability
+                # .md "Numerics") — consumed by the BENCH JSON line and the
+                # cadence A/B alongside host_gap_frac.
+                stats["ledger"] = {
+                    "blocks": ledger_blocks,
+                    "max_energy_drift": max_energy_drift,
+                    **(drift_last or {}),
+                }
+                if ledger_last is not None \
+                        and ledger_last["energy"] is not None:
+                    stats["total_energy"] = float(ledger_last["energy"])
+            if sent_every:
+                stats["sentinel"] = {
+                    "backend": self.backend,
+                    "every": sent_every,
+                    "k": int(self.config.sentinel_k),
+                    "probes": sent_stats["probes"],
+                    "max_rel_err": sent_stats["max_rel_err"],
+                    **{
+                        k: sent_stats["last"][k]
+                        for k in ("median_rel_err", "p90_rel_err")
+                        if sent_stats["last"] is not None
+                    },
+                }
+            if trace_id is not None:
+                stats["trace_id"] = trace_id
+            if config.merge_radius > 0.0:
+                stats["merged_pairs"] = merged_total
+            return self._finish(logger, total_time, total_steps - start_step,
+                                stats)
 
     def _banner(self, logger: Optional[RunLogger], steps: int,
                 integrator_label: str) -> None:
@@ -2529,13 +2558,15 @@ class Simulator:
     def _finish(self, logger: Optional[RunLogger], total_time: float,
                 steps: int, stats: dict) -> dict:
         """Shared run epilogue: perf log, final positions, results dict."""
+        stats["final_state"] = self.final_state()
         if logger is not None:
             logger.performance(
                 total_time, steps, pairs_per_sec=stats["pairs_per_sec"]
             )
-            logger.final_positions(np.asarray(self.final_state().positions))
+            logger.final_positions(
+                np.asarray(stats["final_state"].positions)
+            )
             logger.completed()
-        stats["final_state"] = self.final_state()
         if self.fmm_sparse:
             # Occupancy drift audit: the sparse sizing was fixed from
             # the INITIAL state; a run whose structure spread out can
@@ -2929,12 +2960,13 @@ class Simulator:
     def final_state(self) -> ParticleState:
         """State restricted to the real (unpadded) particles, on host-default
         placement (device_get avoids sharded-slice resharding)."""
-        s = jax.device_get(self.state)
-        return ParticleState(
-            positions=jnp.asarray(s.positions[: self.n_real]),
-            velocities=jnp.asarray(s.velocities[: self.n_real]),
-            masses=jnp.asarray(s.masses[: self.n_real]),
-        )
+        with _tracing.span("run.final_state"):
+            s = jax.device_get(self.state)
+            return ParticleState(
+                positions=jnp.asarray(s.positions[: self.n_real]),
+                velocities=jnp.asarray(s.velocities[: self.n_real]),
+                masses=jnp.asarray(s.masses[: self.n_real]),
+            )
 
     def energy(self):
         """Total conserved energy: kinetic + self-gravity potential +

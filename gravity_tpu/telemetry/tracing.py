@@ -19,6 +19,12 @@ is a picture, not a grep (docs/observability.md "Trace model").
 
 Solo runs (`gravity_tpu run --trace`) emit the same span structure
 (block/checkpoint spans) through the same stream format.
+
+A span timed in place goes through :func:`span`, which also opens a
+profiler annotation ``gravity.<name>``: a ``jax.profiler`` capture
+(``run --profile``) shows it on the same clock as the device's ops,
+whether or not a JSONL stream is attached. Spans stamped after the
+fact (:meth:`Tracer.emit`) reach the JSONL stream only.
 """
 
 from __future__ import annotations
@@ -30,6 +36,8 @@ import os
 import time
 import uuid
 from typing import Optional
+
+import jax
 
 from ..utils.logging import JsonlEventLogger
 
@@ -43,6 +51,10 @@ SPAN_NAMES = (
     # stitched into the job's own trace via the spool-persisted trace
     # id (docs/serving.md "Pod topology & router").
     "route",
+    # The solo fixed-dt driver's phases (Simulator.run), for the
+    # profiler and the JSONL stream alike.
+    "run", "run.open", "block.dispatch", "block.wait", "block.consume",
+    "run.drain", "run.close", "run.final_state",
 )
 
 
@@ -52,6 +64,35 @@ def new_trace_id() -> str:
 
 def new_span_id() -> str:
     return f"sp-{uuid.uuid4().hex[:10]}"
+
+
+@contextlib.contextmanager
+def span(name: str, tracer: Optional["Tracer"] = None,
+         trace: Optional[str] = None, *, parent: Optional[str] = None,
+         **attrs):
+    """One span, into both sinks under one name.
+
+    Always a profiler annotation ``gravity.<name>`` carrying ``attrs``:
+    while no profiler runs it costs about two microseconds and formats
+    nothing. Given a ``tracer`` and a ``trace`` id, also the JSONL span
+    ``name`` with its wall-clock ``t0`` (``time.time_ns``, the clock the
+    profiler stamps its events with). Yields a mutable attrs dict:
+    fields added before exit go to the JSONL record; an exception is
+    recorded there as an ``error`` attr and re-raised."""
+    live = dict(attrs)
+    with jax.profiler.TraceAnnotation("gravity." + name, **attrs):
+        if tracer is None or trace is None:
+            yield live
+            return
+        t0 = time.time_ns() / 1e9
+        try:
+            yield live
+        except BaseException as e:
+            live.setdefault("error", f"{type(e).__name__}: {e}")
+            raise
+        finally:
+            tracer.emit(name, trace, t0, time.time_ns() / 1e9 - t0,
+                        parent=parent, **live)
 
 
 class TraceEventLogger(JsonlEventLogger):
@@ -103,22 +144,10 @@ class Tracer:
             pass  # down the serving path it observes
         return sid
 
-    @contextlib.contextmanager
     def span(self, name: str, trace: str, *,
              parent: Optional[str] = None, **attrs):
-        """Time a block as a span. Yields a mutable attrs dict (add
-        result fields before exit); an exception is recorded as an
-        ``error`` attr and re-raised."""
-        t0 = time.time()
-        live = dict(attrs)
-        try:
-            yield live
-        except BaseException as e:
-            live.setdefault("error", f"{type(e).__name__}: {e}")
-            raise
-        finally:
-            self.emit(name, trace, t0, time.time() - t0, parent=parent,
-                      **live)
+        """Time a block as a span: :func:`span` with this tracer."""
+        return span(name, self, trace, parent=parent, **attrs)
 
     def read(self) -> list:
         if self._log is None:
