@@ -1,25 +1,39 @@
-"""Tiled Pallas TPU kernel for direct-sum pairwise gravity.
+"""Tiled Pallas TPU kernels for direct-sum pairwise gravity.
 
 TPU-native redesign of the reference CUDA kernel
 (`/root/reference/cuda.cu:32-60`). The CUDA kernel is one-thread-per-
 particle over j>i pairs — severely load-imbalanced (thread 0 does N-1
 pairs, thread N-1 does none) and with an unsynchronized cross-thread write
-to ``forces[3j]`` (`cuda.cu:47-49`). Here instead:
+to ``forces[3j]`` (`cuda.cu:47-49`). Two kernels here:
 
-- FlashAttention-style tiling: grid over (i-tile, j-tile); the (N, N)
-  interaction matrix is never materialized. j is the minor grid axis, so
-  each i-tile's accumulator block stays VMEM-resident across the j-stream.
-- Every tile does identical work (full rectangular tile) — no triangular
-  bookkeeping, perfect load balance, and all accumulation is into the
-  block-private accumulator: the reference's data race is impossible by
-  construction.
+- :func:`pallas_accelerations_vs` (targets vs sources: the ring's hops,
+  the multirate kicks, serving). FlashAttention-style tiling: grid over
+  (i-tile, j-tile); the (N, N) interaction matrix is never materialized.
+  j is the minor grid axis, so each i-tile's accumulator block stays
+  VMEM-resident across the j-stream. Every tile does identical work (full
+  rectangular tile) and accumulates into its block-private accumulator.
+- :func:`pallas_pairwise_accelerations` (targets == sources). Newton's
+  third law, as the reference's own j>i loop uses it: each unordered
+  pair of square (T, T) tiles is evaluated once, over the upper triangle
+  of tiles, and serves both bodies: +w_ij·d_ij into body i's row sums,
+  -w_ji·d_ij into body j's column sums. That is ~13 VPU ops per directed
+  pair against the rectangular kernel's ~18. The race the reference had
+  on ``forces[3j]`` cannot happen: the grid runs sequentially on the
+  core's one TensorCore ("arbitrary" semantics), the row sums of tile
+  row i are one output block written back once, and the column sums are
+  one VMEM-resident block that only the current grid step touches.
+  Above :data:`SYM_MAX_N` the column accumulator no longer fits in VMEM
+  and the entry runs the rectangular kernel instead.
 - Mixed layout: target positions are fed as (TI, 3) row-blocks (columns
   sliced to (TI, 1) vectors), source positions as a transposed (3, N) array
   so j-tiles are (3, TJ) with the long axis on lanes — both broadcast
-  cleanly to the (TI, TJ) VPU tiles that carry the ~20-flop pair pipeline.
+  cleanly to the (TI, TJ) VPU tiles that carry the pair pipeline. The
+  symmetric kernel reads both tiles this way, with G·m as a fourth column
+  (row) beside the positions.
 
-The wrapper pads N to tile multiples with zero-mass sources (exact: zero
-mass contributes zero weight) and slices targets back.
+The wrappers pad N to tile multiples with zero-mass bodies (exact: zero
+mass contributes zero weight, on either side of a pair) and slice the
+real bodies back.
 """
 
 from __future__ import annotations
@@ -165,6 +179,117 @@ def pallas_accelerations_vs(
     return acc[:m]
 
 
+def _sym_tile(nb, p, q):
+    """Tile (i, j), i <= j, of step (p, q) of the symmetric grid.
+
+    Grid row p holds triangle rows p and nb-1-p (nb - p and p + 1 tiles:
+    nb + 1 together), each starting at its diagonal tile, so every tile
+    row is one contiguous run of steps and the grid is a rectangle of
+    plain index arithmetic (no tile list in SMEM, which grows as N²).
+    """
+    first = q < nb - p
+    i = jax.lax.select(first, p, nb - 1 - p)
+    j = jax.lax.select(first, p + q, q - 1)
+    return i, j
+
+
+def _nbody_sym_kernel(bi_ref, bjt_ref, acc_ref, col_ref, *, nb, tile,
+                      cutoff, eps, masked):
+    """One tile pair (i <= j) of the symmetric all-pairs sum.
+
+    ``bi_ref`` (T, 4) and ``bjt_ref`` (4, T) hold x, y, z and G·m of
+    tiles i and j. The first step of each tile row is its diagonal tile,
+    which holds both directions of every pair in it and so adds to the
+    row sums only, overwriting the block (its zeroing). An off-diagonal
+    tile adds +w_ij·d to the row sums of tile i and w_ji·d to the column
+    sums of tile j, which the wrapper subtracts. `masked` as in
+    :func:`_nbody_kernel`; the mask is symmetric, so one zeroed inv_r
+    serves both sides.
+    """
+    i, j = _sym_tile(nb, pl.program_id(0), pl.program_id(1))
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        col_ref[...] = jnp.zeros_like(col_ref)
+
+    bi = bi_ref[...]
+    bjt = bjt_ref[...]
+    dx = bjt[0:1, :] - bi[:, 0:1]  # (T, T)
+    dy = bjt[1:2, :] - bi[:, 1:2]
+    dz = bjt[2:3, :] - bi[:, 2:3]
+    dtype = dx.dtype
+    r2_soft = dx * dx + dy * dy + dz * dz + jnp.asarray(eps * eps, dtype)
+    if masked:
+        valid = r2_soft > jnp.asarray(cutoff * cutoff, dtype)
+        inv_r = jnp.where(
+            valid,
+            jax.lax.rsqrt(jnp.where(valid, r2_soft, jnp.asarray(1.0, dtype))),
+            jnp.asarray(0.0, dtype),
+        )
+    else:
+        inv_r = jax.lax.rsqrt(r2_soft)
+    inv_r2 = inv_r * inv_r
+    # G·m folded in before the powers of inv_r, as in _nbody_kernel:
+    # inv_r**3 alone underflows for r > ~2e12 m; inv_r**2 holds to 1e19 m.
+    w_i = (bjt[3:4, :] * inv_r) * inv_r2  # pull of j on i
+    rows = jnp.concatenate(
+        [jnp.sum(w_i * d, axis=1, keepdims=True) for d in (dx, dy, dz)],
+        axis=1,
+    )  # (T, 3)
+
+    @pl.when(i == j)
+    def _():
+        acc_ref[...] = rows
+
+    @pl.when(i != j)
+    def _():
+        acc_ref[...] += rows
+        w_j = (bi[:, 3:4] * inv_r) * inv_r2  # pull of i on j, along -d
+        cols = jnp.concatenate(
+            [jnp.sum(w_j * d, axis=0, keepdims=True) for d in (dx, dy, dz)],
+            axis=0,
+        )  # (3, T)
+        at = pl.ds(pl.multiple_of(j * tile, 128), tile)
+        col_ref[:, at] += cols
+
+
+# VMEM (v5e: 128 MiB per TensorCore) the symmetric kernel may claim: the
+# column accumulator, one (3, N) block in (4, 128) tiles (16 B/body), and
+# the tile's working set, mostly the compiler's spill slots for the (T, T)
+# intermediates (between 24 and 32 MiB at T = 1024, by compiling for the
+# v5e). 4,456,448 bodies: every run autotune sends to the direct sum.
+SYM_VMEM_BYTES = 100 * 2**20
+SYM_TILE_VMEM_BYTES = 32 * 2**20
+SYM_MAX_N = (SYM_VMEM_BYTES - SYM_TILE_VMEM_BYTES) // 16
+
+
+def symmetric_tile(n: int, tile_i: int = TILE_I,
+                   tile_j: int = TILE_J) -> int | None:
+    """The square tile of the symmetric kernel for ``n`` bodies, or None
+    where ``n`` takes the rectangular kernel (above :data:`SYM_MAX_N`).
+
+    The tile has the area of ``tile_i`` × ``tile_j`` (the defaults give
+    1024), at least 128 lanes, and at most half of ``n`` rounded up, so
+    the grid has an even number of tile rows, at least two.
+    """
+    if n > SYM_MAX_N:
+        return None
+    tile = _round_up(int(round((tile_i * tile_j) ** 0.5)), 128)
+    return min(tile, _round_up(-(-n // 2), 128))
+
+
+def pair_symmetric_share(n: int, tile_i: int = TILE_I,
+                         tile_j: int = TILE_J) -> float:
+    """Share of the pair tiles :func:`pallas_pairwise_accelerations`
+    evaluates for ``n`` bodies that serve both of their bodies: (nb-1) /
+    (nb+1) over nb tile rows; 0 where the rectangular kernel runs."""
+    tile = symmetric_tile(n, tile_i, tile_j)
+    if tile is None:
+        return 0.0
+    nb = _round_up(-(-n // tile), 2)
+    return (nb - 1) / (nb + 1)
+
+
 @partial(
     jax.jit,
     static_argnames=("g", "cutoff", "eps", "tile_i", "tile_j", "interpret"),
@@ -180,12 +305,82 @@ def pallas_pairwise_accelerations(
     tile_j: int = TILE_J,
     interpret: bool = False,
 ) -> jax.Array:
-    """All-pairs accelerations (targets == sources)."""
-    return pallas_accelerations_vs(
-        positions, positions, masses,
-        g=g, cutoff=cutoff, eps=eps,
-        tile_i=tile_i, tile_j=tile_j, interpret=interpret,
+    """All-pairs accelerations (targets == sources), each unordered pair
+    evaluated once; the rectangular kernel above :data:`SYM_MAX_N`."""
+    n = positions.shape[0]
+    tile = symmetric_tile(n, tile_i, tile_j)
+    if tile is None:
+        return pallas_accelerations_vs(
+            positions, positions, masses,
+            g=g, cutoff=cutoff, eps=eps,
+            tile_i=tile_i, tile_j=tile_j, interpret=interpret,
+        )
+    dtype = positions.dtype
+    nb = _round_up(-(-n // tile), 2)
+    npad = nb * tile
+    bodies = (
+        jnp.zeros((npad, 4), dtype)
+        .at[:n, :3].set(positions)
+        .at[:n, 3].set(jnp.asarray(g, dtype) * masses)
     )
+    kernel = functools.partial(
+        _nbody_sym_kernel, nb=nb, tile=tile, cutoff=cutoff, eps=eps,
+        masked=eps * eps <= cutoff * cutoff,
+    )
+    tiles = nb * (nb + 1) // 2
+    rows, cols = pl.pallas_call(
+        kernel,
+        grid=(nb // 2, nb + 1),
+        in_specs=[
+            pl.BlockSpec((tile, 4), lambda p, q: (_sym_tile(nb, p, q)[0], 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((4, tile), lambda p, q: (0, _sym_tile(nb, p, q)[1]),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=[
+            pl.BlockSpec((tile, 3), lambda p, q: (_sym_tile(nb, p, q)[0], 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((3, npad), lambda p, q: (0, 0),
+                         memory_space=pltpu.VMEM,
+                         pipeline_mode=pl.Buffered(1)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((npad, 3), dtype),
+            jax.ShapeDtypeStruct((3, npad), dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=SYM_VMEM_BYTES,
+        ),
+        cost_estimate=pl.CostEstimate(
+            flops=26 * tiles * tile * tile,
+            bytes_accessed=(nb * tile * 4 + tiles * 4 * tile
+                            + npad * 6) * 4,
+            transcendentals=tiles * tile * tile,  # rsqrt
+        ),
+        interpret=interpret,
+    )(bodies, bodies.T)
+    return (rows - cols.T)[:n]
+
+
+def make_pallas_pairwise_kernel(
+    *, g: float = G, cutoff: float = CUTOFF_RADIUS, eps: float = 0.0,
+    interpret: bool = False,
+):
+    """(positions, masses) -> accelerations through
+    :func:`pallas_pairwise_accelerations`, differentiable as
+    :func:`make_pallas_local_kernel` is."""
+    from .forces import wrap_with_dense_vjp
+
+    def _forward(pos_i, pos_j, masses_j):
+        del pos_j  # the same array as pos_i
+        return pallas_pairwise_accelerations(
+            pos_i, masses_j, g=g, cutoff=cutoff, eps=eps,
+            interpret=interpret,
+        )
+
+    kernel = wrap_with_dense_vjp(_forward, g=g, cutoff=cutoff, eps=eps)
+    return lambda pos, m: kernel(pos, pos, m)
 
 
 def make_pallas_local_kernel(

@@ -820,6 +820,9 @@ class Simulator:
         # tiles/eval) — set by the nlist accel builder; the bench
         # harness reads it for the honest roofline.
         self.nlist_sizing = None
+        # Share of the evaluated pair tiles that serve both of their
+        # bodies (the symmetric Pallas all-pairs kernel; 0 elsewhere).
+        self.pair_symmetric_share = 0.0
 
         # State before backend resolution: plain 'auto' routes through
         # the measurement-driven autotuner (gravity_tpu/autotune.py),
@@ -1511,7 +1514,17 @@ class Simulator:
                 pos, pos, m, rcut=config.nlist_rcut, side=side, cap=cap,
                 box=config.periodic_box, _self=True, **common,
             )
-        if self.backend in ("pallas", "pallas-mxu", "cpp"):
+        if self.backend == "pallas":
+            from .ops.pallas_forces import (
+                make_pallas_pairwise_kernel,
+                pair_symmetric_share,
+            )
+
+            self.pair_symmetric_share = pair_symmetric_share(n)
+            return make_pallas_pairwise_kernel(
+                interpret=jax.devices()[0].platform != "tpu", **common
+            )
+        if self.backend in ("pallas-mxu", "cpp"):
             kernel = make_local_kernel(config, self.backend)
             return lambda pos, m: kernel(pos, pos, m)
         if self.backend == "tree":
@@ -2473,6 +2486,7 @@ class Simulator:
             stats["autotune_cache"] = self.autotune["cache"]
             stats["autotune_probe_ms"] = self.autotune["probe_ms"]
             stats["host_gap_frac"] = gap.host_gap_frac
+            stats["pair_symmetric_share"] = self.pair_symmetric_share
             # Performance observatory (docs/observability.md
             # "Performance"): the perf facts promoted into the run's
             # metrics registry when a telemetry bundle is attached — the

@@ -1,10 +1,12 @@
-"""Pallas force kernel vs the jnp reference kernel (interpret mode on CPU).
+"""Pallas force kernels vs the jnp reference kernel (interpret mode on CPU).
 
 The debug-mode race check from SURVEY §5: the pure-jnp kernel is the ground
-truth the Pallas kernel must match (the TPU analog of running
+truth the Pallas kernels must match (the TPU analog of running
 compute-sanitizer against the CUDA kernel — except here divergence is the
-only possible failure class, since block-private accumulation makes the
-reference's `forces[3j]` race impossible by construction).
+only possible failure class, since one writer per accumulator makes the
+reference's `forces[3j]` race impossible by construction). The all-pairs
+entry evaluates each pair of tiles once (Newton's third law); it must
+match both the dense sum and the rectangular kernel.
 """
 
 import jax
@@ -16,9 +18,13 @@ from gravity_tpu.ops.forces import (
     accelerations_vs,
     pairwise_accelerations_dense,
 )
+from gravity_tpu.ops import pallas_forces
 from gravity_tpu.ops.pallas_forces import (
+    SYM_MAX_N,
+    pair_symmetric_share,
     pallas_accelerations_vs,
     pallas_pairwise_accelerations,
+    symmetric_tile,
 )
 
 
@@ -103,3 +109,91 @@ def test_padding_is_exact(key):
     np.testing.assert_allclose(
         np.asarray(ragged), np.asarray(expected), rtol=2e-5, atol=1e-12
     )
+
+
+@pytest.mark.parametrize("eps", [0.0, 1e10], ids=["masked", "mask_free"])
+@pytest.mark.parametrize(
+    "n", [100, 512, 1000], ids=["one_tile", "four_tiles", "ragged"]
+)
+def test_symmetric_matches_dense_and_rectangular(key, n, eps):
+    """The symmetric all-pairs kernel (square 128 tiles from tile_i=32,
+    tile_j=128) == the dense sum == the rectangular kernel, on both the
+    masked path (eps = 0: two coincident bodies in different tiles give
+    each other zero, no NaN) and the mask-free one (eps > cutoff)."""
+    assert symmetric_tile(n, 32, 128) == 128
+    pos, masses = _random_system(key, n)
+    pos = pos.at[n - 1].set(pos[0])  # coincident, a tile apart for n > 128
+    got = pallas_pairwise_accelerations(
+        pos, masses, eps=eps, tile_i=32, tile_j=128, interpret=True
+    )
+    rect = pallas_accelerations_vs(
+        pos, pos, masses, eps=eps, tile_i=32, tile_j=128, interpret=True
+    )
+    expected = pairwise_accelerations_dense(pos, masses, eps=eps)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    for want in (expected, rect):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-5, atol=1e-12
+        )
+
+
+@pytest.mark.parametrize("form", ["symmetric", "rectangular"])
+def test_total_momentum_at_fp32_rounding(form):
+    """Σ m_i a_i vanishes to fp32 rounding of the sums, |Σ m_i a_i| <
+    1e-7 Σ |m_i a_i|, for unequal masses: the column sums carry each
+    pair's reaction -w_ji·d with the mass of the other body. Both kernels
+    land at ~1e-9; neither is reliably the closer (each pair's two terms
+    are as antisymmetric in one kernel as in the other: only the order
+    of the sums differs)."""
+    pos, masses = _random_system(jax.random.PRNGKey(7), 640)
+    if form == "symmetric":
+        acc = pallas_pairwise_accelerations(
+            pos, masses, tile_i=32, tile_j=128, interpret=True
+        )
+    else:
+        acc = pallas_accelerations_vs(
+            pos, pos, masses, tile_i=32, tile_j=128, interpret=True
+        )
+    p = np.asarray(masses, np.float64)[:, None] * np.asarray(acc, np.float64)
+    assert np.linalg.norm(p.sum(axis=0)) < 1e-7 * np.abs(p).sum()
+
+
+def test_form_chosen_by_n(key, monkeypatch):
+    """N alone picks the form: the benchmark's 262,144 bodies take the
+    symmetric kernel on 1024 tiles (255 of 257 tiles per tile-row pair
+    serve both bodies); above the VMEM bound the entry is the
+    rectangular kernel, and pair_symmetric_share reads 0."""
+    assert symmetric_tile(262_144) == 1024
+    assert pair_symmetric_share(262_144) == pytest.approx(255 / 257)
+    assert symmetric_tile(SYM_MAX_N) is not None
+    assert symmetric_tile(SYM_MAX_N + 1) is None
+    assert pair_symmetric_share(SYM_MAX_N + 1) == 0.0
+    # The fallback, at a size the interpreter can run: the rectangular
+    # kernel's output, bit for bit.
+    n = 333
+    monkeypatch.setattr(pallas_forces, "SYM_MAX_N", n - 1)
+    assert pair_symmetric_share(n) == 0.0
+    pos, masses = _random_system(key, n)
+    got = pallas_pairwise_accelerations(
+        pos, masses, tile_i=32, tile_j=128, interpret=True
+    )
+    rect = pallas_accelerations_vs(
+        pos, pos, masses, tile_i=32, tile_j=128, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(rect))
+
+
+def test_run_stats_report_pair_symmetric_share():
+    """The solo Pallas run reports the share of its pair tiles that
+    served both bodies; the ring, whose hops all run the rectangular
+    kernel, reports 0."""
+    from gravity_tpu.config import SimulationConfig
+    from gravity_tpu.simulation import Simulator
+
+    base = dict(model="random", n=256, steps=2, dt=3600.0, seed=1,
+                force_backend="pallas", integrator="leapfrog")
+    solo = Simulator(SimulationConfig(**base)).run()
+    assert solo["pair_symmetric_share"] == pair_symmetric_share(256) == 1 / 3
+    ring = Simulator(SimulationConfig(**base, sharding="ring"))
+    assert ring.mesh is not None
+    assert ring.run()["pair_symmetric_share"] == 0.0

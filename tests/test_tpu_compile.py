@@ -18,6 +18,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from gravity_tpu.ops.pallas_forces import SYM_MAX_N
+
 N_FLAGSHIP = 262_144  # chip_smoke.py's flagship and bench.py's size
 
 
@@ -54,6 +56,24 @@ def test_vpu_direct_sum_compiles(one_chip):
     text = _compiled_text(
         partial(pallas_accelerations_vs, eps=1e9, interpret=False),
         one_chip, (n, 3), (n, 3), (n,),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n", [N_FLAGSHIP, SYM_MAX_N])
+def test_symmetric_direct_sum_compiles(one_chip, n):
+    """The all-pairs entry's symmetric kernel at the flagship size and at
+    the largest N it takes, where its column accumulator fills the VMEM
+    it claims."""
+    from gravity_tpu.ops.pallas_forces import (
+        pallas_pairwise_accelerations,
+        symmetric_tile,
+    )
+
+    assert symmetric_tile(n) == 1024
+    text = _compiled_text(
+        partial(pallas_pairwise_accelerations, eps=1e9, interpret=False),
+        one_chip, (n, 3), (n,),
     )
     assert "tpu_custom_call" in text
 
