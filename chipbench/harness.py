@@ -5,20 +5,29 @@
 1. Finds the cell's files by name (``registry``) and refuses to run,
    printing no result, unless JAX sees a TPU with the chips the cell
    asks for and ``peaks.json`` knows its kind.
-2. Makes the initial conditions on the device from ``--seed``
-   (``ics.py``) and builds ``Simulator(config, state)``.
+2. Makes the initial conditions on the device from ``--seed`` by the
+   scenario the configuration names (``ics.py``, ``scenarios/``) and
+   builds ``Simulator(config, state)``. The traffic switches on the
+   program's own outputs (``OUTPUTS``), written under ``.chipbench/out/``
+   of the checkout.
 3. Warms up with one block through ``Simulator.run`` itself: every
    program the window runs compiles (or loads from the persistent cache
    in ``.jax_cache/`` of the checkout) there, and counts as set-up.
-   Then the simulator's state is put back to the initial conditions,
-   so the window starts from what the benchmark made.
-4. The window: one ``Simulator.run(steps=S)``, S whole blocks, ended by
-   a value-fetch fence. S is fixed by ``--seconds`` and the cell's
-   nominal step time, so every seed does the same work.
-5. Compares the window's final state with the plain reference
-   (``reference/window.py``) at bodies drawn from the seed, prints each
-   number compared beside its limit as the last lines of stderr, and the
-   result as the last line of stdout.
+   Then the simulator's state is put back to the initial conditions
+   and the outputs emptied, so the window starts from what the
+   benchmark made.
+4. The window: one ``Simulator.run``, S whole blocks, ended by a
+   value-fetch fence; the outputs are written inside it. S is fixed by
+   ``--seconds`` and the cell's nominal step time, so every seed does
+   the same work. With checkpoints on, the warm block and the window
+   count their steps from the step before a checkpoint's (``first``),
+   as a stretch of a longer run: the first block of each ends on a
+   checkpoint, so the window holds one and the first save is warm.
+5. Compares the window's final state with the plain reference the
+   configuration names (``reference/``) at bodies drawn from the seed,
+   and the outputs with the final state and with each other; prints
+   each number compared beside its limit as the last lines of stderr,
+   and the result as the last line of stdout.
 """
 
 from __future__ import annotations
@@ -26,6 +35,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import gc
+import inspect
 import json
 import os
 import shutil
@@ -36,6 +46,11 @@ from chipbench import registry
 
 CACHE_DIR = os.path.join(registry.ROOT, ".jax_cache")
 TRACE_DIR = os.path.join(registry.ROOT, ".chipbench", "trace")
+OUT_DIR = os.path.join(registry.ROOT, ".chipbench", "out")
+# The program's outputs a traffic file may switch on: positions every
+# so many steps, a checkpoint every so many steps. Each is checked after
+# the window, exactly (limit 0).
+OUTPUTS = ("trajectory_every", "checkpoint_every")
 REFERENCE_CPU_DEVICES = 4
 _COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                    "/jax/core/compile/jaxpr_trace_duration")
@@ -119,10 +134,130 @@ def _device_line(devs, used) -> dict:
             "count": len(devs), "memory_peak_bytes": peak}
 
 
+def _reference_kwargs(fn, sim: dict) -> dict:
+    """The keys of the simulation dict that the reference's
+    ``integrate_targets`` takes as keyword parameters (``steps`` is the
+    window's, passed apart)."""
+    return {k: sim[k] for k, p in inspect.signature(fn).parameters.items()
+            if p.kind is p.KEYWORD_ONLY and k != "steps" and k in sim}
+
+
+def _open_outputs(config, n: int) -> dict:
+    """An empty output directory, and the writers ``Simulator.run``
+    takes for the outputs ``config`` switches on, made as the program's
+    CLI makes them."""
+    shutil.rmtree(config.log_dir, ignore_errors=True)
+    os.makedirs(config.log_dir)
+    io = {}
+    if config.record_trajectories:
+        from gravity_tpu.utils.trajectory import TrajectoryWriter
+
+        # every=1: the simulator strides the frames itself.
+        io["trajectory_writer"] = TrajectoryWriter(
+            os.path.join(config.log_dir, "trajectories"), n, every=1)
+    if config.checkpoint_every:
+        from gravity_tpu.utils.checkpoint import make_checkpoint_manager
+
+        io["checkpoint_manager"] = make_checkpoint_manager(
+            config.checkpoint_dir)
+    return io
+
+
+def _close_outputs(io: dict) -> None:
+    if "checkpoint_manager" in io:
+        io["checkpoint_manager"].close()
+
+
+def _bits(a):
+    import numpy as np
+
+    return np.ascontiguousarray(a, np.float32).view(np.uint32)
+
+
+def _read_frames(path: str) -> dict:
+    """``{step: positions (n, 3)}`` of a trajectory written as .npy
+    shards with a ``manifest.json``; {} where there is none."""
+    import numpy as np
+
+    try:
+        with open(os.path.join(path, "manifest.json")) as f:
+            shards = json.load(f)["shards"]
+    except (OSError, ValueError, KeyError):
+        return {}
+    frames = {}
+    for shard in shards:
+        frames.update(zip(shard["steps"],
+                          np.load(os.path.join(path, shard["file"]))))
+    return frames
+
+
+def _checkpoint_faults(path: str, due: list, n: int, frames: dict) -> int:
+    """Checkpoints on disk that are not due, do not load, have the wrong
+    shape or positions other than the trajectory's frame of their step;
+    plus one where the newest due checkpoint is not among those sound."""
+    import numpy as np
+    import orbax.checkpoint as ocp
+
+    if not os.path.isdir(path):
+        return 1
+    faults, sound = 0, set()
+    mgr = ocp.CheckpointManager(path)
+    try:
+        for step in mgr.all_steps():
+            try:
+                got = dict(mgr.restore(step,
+                                       args=ocp.args.StandardRestore()))
+            except Exception:  # noqa: BLE001 — any unreadable snapshot
+                faults += 1
+                continue
+            shapes = tuple(np.shape(got.get(k)) for k in
+                           ("positions", "velocities", "masses"))
+            ok = (step in due and shapes == ((n, 3), (n, 3), (n,))
+                  and (step not in frames or np.array_equal(
+                      _bits(got["positions"]), _bits(frames[step]))))
+            if ok:
+                sound.add(step)
+            else:
+                faults += 1
+    finally:
+        mgr.close()
+    return faults + (0 if due and due[-1] in sound else 1)
+
+
+def output_gaps(config, *, first: int, steps: int, x_final,
+                idx) -> dict:
+    """The outputs the traffic switched on, checked after a window of
+    steps ``first + 1 .. first + steps``. Each gap is a count of faults,
+    held to 0:
+
+    - ``trajectory_frames_off``: frames missing or not due (one every
+      ``trajectory_every`` steps of the window);
+    - ``trajectory_last_frame_diff``: values of the last frame that
+      differ, bit for bit, from the final state at the sampled bodies;
+    - ``checkpoint_faults``: ``_checkpoint_faults``."""
+    gaps, frames = {}, {}
+    if config.record_trajectories:
+        every = config.trajectory_every
+        frames = _read_frames(os.path.join(config.log_dir, "trajectories"))
+        want = {first + every * (k + 1) for k in range(steps // every)}
+        gaps["trajectory_frames_off"] = len(want ^ set(frames))
+        last = frames.get(first + steps)
+        gaps["trajectory_last_frame_diff"] = (
+            3 * len(idx) if last is None else
+            int((_bits(last[idx]) != _bits(x_final[idx])).sum()))
+    if config.checkpoint_every:
+        every = config.checkpoint_every
+        due = [s for s in range(first + 1, first + steps + 1)
+               if s % every == 0]
+        gaps["checkpoint_faults"] = _checkpoint_faults(
+            config.checkpoint_dir, due, len(x_final), frames)
+    return gaps
+
+
 def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
              t0: float, age0: float = 0.0, peaks: dict | None = None,
              sim_overrides: dict | None = None,
-             trace_dir: str = TRACE_DIR) -> dict:
+             trace_dir: str = TRACE_DIR, out_dir: str = OUT_DIR) -> dict:
     """Set up, measure and check one run of ``cell``; returns the result
     line's dict. ``sim_overrides`` sizes a rehearsal or switches on the
     program's own lower-precision path (tests and the control only)."""
@@ -139,6 +274,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     from gravity_tpu.state import ParticleState
 
     cfg, traffic = cell["config_data"], cell["traffic_data"]
+    base = cell["base"]
     sim_kw = dict(cfg["simulation"], **(sim_overrides or {}))
     n, block = sim_kw["n"], cell["block_steps"]
     steps = window_steps(seconds, block, cell["nominal_step_s"])
@@ -146,26 +282,39 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         sim_kw["mesh_shape"] = tuple(sim_kw["mesh_shape"])
     sim_kw.update(steps=steps, progress_every=block,
                   io_pipeline=traffic["io_pipeline"])
+    outs = {k: traffic[k] for k in OUTPUTS if traffic.get(k)}
+    first = 0
+    if outs:
+        logs = os.path.join(out_dir, cell["name"])
+        sim_kw.update(
+            record_trajectories="trajectory_every" in outs,
+            trajectory_every=outs.get("trajectory_every", 1),
+            checkpoint_every=outs.get("checkpoint_every", 0),
+            log_dir=logs, checkpoint_dir=os.path.join(logs, "checkpoints"))
+        if "checkpoint_every" in outs:
+            first = -block % outs["checkpoint_every"]
     devs = jax.devices()
     used = devs[:cell["chips"]]
 
     # --- set-up: inputs from the seed, the simulator, one warm block ---
     with jax.default_device(devs[0]):
-        pos, vel, m = ics.plummer(
-            ics.seed_key(seed), n=n, total_mass=cfg["total_mass"],
-            scale_radius=cfg["scale_radius"], g=sim_kw["g"])
+        pos, vel, m = ics.make(dict(cfg, simulation=sim_kw), seed, base=base)
     x0, v0, masses = (np.asarray(a) for a in (pos, vel, m))
-    sim = Simulator(SimulationConfig(**sim_kw),
-                    state=ParticleState(pos, vel, m))
+    config = SimulationConfig(**sim_kw)
+    sim = Simulator(config, state=ParticleState(pos, vel, m))
     del pos, vel, m
     start = sim.state  # run() never donates the state it starts from
     with jax.profiler.TraceAnnotation("chipbench.warmup"):
+        io = _open_outputs(config, n) if outs else {}
         # The fence's reduction warms on the arrays the window ends on.
-        warm = sim.run(steps=block)["final_state"]
+        warm = sim.run(steps=first + block, start_step=first,
+                       **io)["final_state"]
         fence(warm.positions, warm.velocities)
+        _close_outputs(io)
         del warm
     sim.state = start
     del start
+    io = _open_outputs(config, n) if outs else {}
 
     # --- the window ---
     compiles = _CompileCount()
@@ -179,7 +328,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         compiles.on = True
         t_w0 = time.perf_counter()
         with jax.profiler.TraceAnnotation(WINDOW_START):
-            stats = sim.run(steps=steps)
+            stats = sim.run(steps=first + steps, start_step=first, **io)
         with jax.profiler.TraceAnnotation(WINDOW_END):
             final = stats.pop("final_state")
             fence(final.positions, final.velocities)
@@ -189,6 +338,7 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         if trace:
             jax.profiler.stop_trace()
         jax.monitoring.unregister_event_duration_listener(compiles)
+        _close_outputs(io)
     setup_s = age0 + (t_w0 - t0)
     window_s = t_w1 - t_w0
     device = _device_line(devs, used)
@@ -200,13 +350,20 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     t_c0 = time.perf_counter()
     with jax.profiler.TraceAnnotation("chipbench.check"):
         idx = ref.sample_targets(seed, n, cell["sample_targets"])
-        x_ref, v_ref = ref.integrate_targets(
-            x0, v0, masses, idx, dt=sim_kw["dt"], steps=steps,
-            g=sim_kw["g"], eps=sim_kw["eps"], cutoff=sim_kw["cutoff"])
+        check = registry.module("reference", cfg["check"],
+                                base=base).integrate_targets
+        x_ref, v_ref = check(x0, v0, masses, idx, steps=steps,
+                             **_reference_kwargs(check, sim_kw))
         gaps = ref.compare(x0[idx], v0[idx], xS[idx], vS[idx], x_ref, v_ref)
+        out_gaps = {}
+        if outs:
+            out_gaps = output_gaps(config, first=first, steps=steps,
+                                   x_final=xS, idx=idx)
+            shutil.rmtree(config.log_dir, ignore_errors=True)
     check_s = time.perf_counter() - t_c0
-    limits = cell["limits"]
-    checks = {k: {"value": gaps[k], "limit": v} for k, v in limits.items()}
+    checks = {k: {"value": gaps[k], "limit": v}
+              for k, v in cell["limits"].items()}
+    checks.update({k: {"value": v, "limit": 0} for k, v in out_gaps.items()})
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
     evals = (steps * cfg["force_evals"]["per_step"]

@@ -1,0 +1,26 @@
+"""Device idle while ``Simulator.run`` drains and closes.
+
+Each idle nanosecond of a device inside the window is charged to the
+innermost span over it, the program's (``gravity.*``) or the harness's
+(``chipbench.*``), as ``spans.py`` does; this is the part charged to
+``spans.CLOSE``: ``gravity.run.drain`` (the host writer's barrier,
+the trajectory's close), ``gravity.run.close`` (stats, ``_finish``)
+and ``gravity.run.final_state`` (the state's copy to the host and
+back), as % of the window, the mean over the chips used.
+None on a trace with no program spans.
+"""
+
+from chipbench import spans
+
+LAYER = "driver block loop"
+UNIT = "%"
+BETTER = "lower"
+MOVES = "step_s"
+SOURCE = "device_trace"
+
+
+def read(f):
+    if f.trace is None:
+        return None
+    return spans.share(f.trace, f.trace.host_spans + f.trace.program_spans,
+                       spans.CLOSE)

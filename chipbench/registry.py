@@ -1,8 +1,15 @@
 """Find the benchmark's parts by name: nothing here names a cell.
 
 - ``configs/<config>.json``: one deployment (scenario, sizes, solver,
-  sharding) with its source, ``assumed`` and ``reduced``;
-- ``traffic/<traffic>.json``: one mix, read by the harness's one loop;
+  sharding) with its source, ``assumed`` and ``reduced``; its ``"ics"``
+  names the scenario and its ``"check"`` the reference;
+- ``scenarios/<ics>.py``: one scenario's initial conditions, a module
+  with ``make(key, cfg) -> (positions, velocities, masses)``;
+- ``reference/<check>.py``: one plain reference, a module with
+  ``integrate_targets(x0, v0, m, idx, *, steps, ...)`` whose other
+  keyword parameters are keys of the configuration's ``simulation``;
+- ``traffic/<traffic>.json``: one mix, read by the harness's one loop,
+  with the program's outputs it switches on;
 - ``workloads/<cell>.json``: one cell: its config, traffic, chips, why,
   and the two numbers that size its window (``block_steps``,
   ``nominal_step_s``);
@@ -51,24 +58,28 @@ def cell(name: str, *, base: str = HERE) -> dict:
     w["name"] = name
     w["config_data"] = _load("configs", w["config"], base=base)
     w["traffic_data"] = _load("traffic", w["traffic"], base=base)
+    w["base"] = base
     return w
+
+
+def module(kind: str, name: str, *, base: str = HERE):
+    """The module ``<base>/<kind>/<name>.py``, loaded from its file."""
+    path = os.path.join(base, kind, _check(name) + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no {kind} module named {name!r} ({path})")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{kind}_{re.sub(r'[^A-Za-z0-9_]', '_', name)}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def metric_modules(*, base: str = HERE) -> dict:
     """Every per-layer metric module under ``metrics/``, by name."""
-    out = {}
     mdir = os.path.join(base, "metrics")
-    for fn in sorted(os.listdir(mdir)):
-        if not fn.endswith(".py") or fn.startswith("_"):
-            continue
-        name = _check(fn[:-3])
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}",
-            os.path.join(mdir, fn))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        out[name] = mod
-    return out
+    return {fn[:-3]: module("metrics", fn[:-3], base=base)
+            for fn in sorted(os.listdir(mdir))
+            if fn.endswith(".py") and not fn.startswith("_")}
 
 
 def benchmark(*, root: str = ROOT) -> dict:

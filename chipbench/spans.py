@@ -10,27 +10,20 @@ shorter on a tie). Idle time that no span covers is charged to ``None``.
 So the charges of one device sum to its idle time in the window, and the
 mean over devices of all of them is the idle of ``device.idle_share``.
 
-``DeviceTrace`` keeps only the harness's spans; the program's are read
-from the loaded trace with :func:`host_spans`.
+``DeviceTrace`` keeps both: ``host_spans`` (the harness's) and
+``program_spans`` (the program's).
 """
 
 from __future__ import annotations
 
-from chipbench.trace import DEVICE_PLANE, minus
+from chipbench.trace import PROGRAM, minus
 
-PROGRAM = "gravity."
 # The driver's phases that open and close every Simulator.run.
 OPEN = ("gravity.run.open",)
 CLOSE = ("gravity.run.drain", "gravity.run.close", "gravity.run.final_state")
-
-
-def host_spans(trace: dict, prefix: str = PROGRAM) -> list:
-    """``[name, start, end]`` of the host events of a loaded trace
-    (``trace.load_xplane``) whose names start with ``prefix``."""
-    return [[name, s, s + d]
-            for p in trace["planes"] if not DEVICE_PLANE.match(p["name"])
-            for line in p["lines"] for name, s, d, _ in line["events"]
-            if name.startswith(prefix)]
+# The host's work on a finished block's outputs, and the checkpoint
+# saves it hands to the background writer.
+CONSUME = ("gravity.block.consume", "gravity.checkpoint")
 
 
 def innermost(spans) -> list:

@@ -17,6 +17,8 @@ import re
 
 WINDOW_START = "chipbench.window.run"
 WINDOW_END = "chipbench.window.fence"
+HARNESS = "chipbench."
+PROGRAM = "gravity."
 # Device planes as the TPU runtime names them, and the line of one
 # event per executed HLO op on each.
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -44,6 +46,15 @@ def load_xplane(path: str) -> dict:
                 for ev in line.events]})
         planes.append({"name": plane.name, "lines": lines})
     return {"planes": planes}
+
+
+def host_events(trace: dict, prefix: str) -> list:
+    """``[name, start, end]`` of the host events of a loaded trace whose
+    names start with ``prefix``."""
+    return [[name, s, s + d]
+            for p in trace["planes"] if not DEVICE_PLANE.match(p["name"])
+            for line in p["lines"] for name, s, d, _ in line["events"]
+            if name.startswith(prefix)]
 
 
 def union(intervals) -> list:
@@ -90,7 +101,9 @@ def matches(event, patterns) -> bool:
 
 
 class DeviceTrace:
-    """The device ops of a trace inside the harness's annotated window."""
+    """The device ops of a trace inside the harness's annotated window,
+    with the host spans of the harness (``host_spans``) and of the
+    program (``program_spans``), each ``[name, start, end]``."""
 
     def __init__(self, trace: dict):
         self.window = _window(trace)
@@ -109,11 +122,8 @@ class DeviceTrace:
                     if e > s:
                         evs.append([name, s, e - s, stats])
             self.devices.append(evs)
-        self.host_spans = [
-            [name, s, s + d]
-            for p in trace["planes"] if not DEVICE_PLANE.match(p["name"])
-            for line in p["lines"] for name, s, d, _ in line["events"]
-            if name.startswith("chipbench.")]
+        self.host_spans = host_events(trace, HARNESS)
+        self.program_spans = host_events(trace, PROGRAM)
 
     @property
     def window_ns(self) -> float:
@@ -133,8 +143,10 @@ class DeviceTrace:
 
     def breakdown(self, top: int = 10) -> dict:
         """The ops that took most device time (mean over devices), and
-        the longest idle gaps of device 0, named by the harness's span
-        that covers the gap's middle."""
+        the longest idle gaps of device 0, each named by the innermost
+        span, the harness's or the program's, over the gap's middle: of
+        those open then, the one opened last (the shorter on a tie), as
+        ``spans.innermost`` charges idle time."""
         n = max(len(self.devices), 1)
         per_op: dict = {}
         for evs in self.devices:
@@ -147,12 +159,12 @@ class DeviceTrace:
         if self.devices:
             lo, hi = self.window
             idle = minus([[lo, hi]], self.intervals(0))
+            spans = self.host_spans + self.program_spans
             for s, e in sorted(idle, key=lambda g: g[0] - g[1])[:top]:
                 mid = 0.5 * (s + e)
-                inner = [sp for sp in self.host_spans
-                         if sp[1] <= mid <= sp[2]]
-                label = (min(inner, key=lambda sp: sp[2] - sp[1])[0]
-                         if inner else "no harness span")
+                inner = [sp for sp in spans if sp[1] <= mid <= sp[2]]
+                label = (max(inner, key=lambda sp: (sp[1], -sp[2]))[0]
+                         if inner else "no span")
                 gaps.append([f"idle in {label}", (e - s) / 1e9])
         return {"device_ops": [[k, v / 1e9] for k, v in ops],
                 "idle_gaps": gaps}

@@ -8,7 +8,8 @@ For each seed, one JSON line per reading, all in one process (the cell
 compiles once):
 
 - ``control_ref_bf16`` (``--ref-bf16``): the reference put in the program's place with
-  its force evaluation in bfloat16 (``reference/window.py``), from the
+  its force evaluation in bfloat16 (the configuration's ``"check"``,
+  ``reference/window.py`` for every cell so far), from the
   seed's initial conditions, compared with the float64 reference the
   way a run compares the program;
 - ``program`` (``--program``): one run of the cell as the driver makes
@@ -36,8 +37,9 @@ from chipbench import harness, registry  # noqa: E402
 
 def control_ref_bf16(cell: dict, seed: int, seconds: float,
                      sim_overrides: dict | None = None) -> dict:
-    """The control's gaps for ``seed`` (float64 reference vs the same
-    reference in bfloat16), from the benchmark's initial conditions."""
+    """The control's gaps for ``seed`` (the configuration's reference
+    vs the same reference in bfloat16), from the benchmark's initial
+    conditions."""
     import jax
     import numpy as np
 
@@ -50,15 +52,14 @@ def control_ref_bf16(cell: dict, seed: int, seconds: float,
     steps = harness.window_steps(seconds, cell["block_steps"],
                                  cell["nominal_step_s"])
     with jax.default_device(jax.devices()[0]):
-        x0, v0, m = (np.asarray(a) for a in ics.plummer(
-            ics.seed_key(seed), n=n, total_mass=cfg["total_mass"],
-            scale_radius=cfg["scale_radius"], g=sim["g"]))
+        x0, v0, m = (np.asarray(a) for a in ics.make(
+            dict(cfg, simulation=sim), seed, base=cell["base"]))
     idx = ref.sample_targets(seed, n, cell["sample_targets"])
-    kw = dict(dt=sim["dt"], steps=steps, g=sim["g"], eps=sim["eps"],
-              cutoff=sim["cutoff"])
-    x_r, v_r = ref.integrate_targets(x0, v0, m, idx, **kw)
-    x_c, v_c = ref.integrate_targets(x0, v0, m, idx, pair_dtype="bfloat16",
-                                     **kw)
+    check = registry.module("reference", cfg["check"],
+                            base=cell["base"]).integrate_targets
+    kw = dict(steps=steps, **harness._reference_kwargs(check, sim))
+    x_r, v_r = check(x0, v0, m, idx, **kw)
+    x_c, v_c = check(x0, v0, m, idx, pair_dtype="bfloat16", **kw)
     return ref.compare(x0[idx], v0[idx], x_c, v_c, x_r, v_r)
 
 

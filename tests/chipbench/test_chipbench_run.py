@@ -2,12 +2,15 @@
 
 The harness's look for a chip is skipped by calling ``run_cell`` from
 the test: the rest of a run (inputs from the seed, the warm block, the
-window through ``Simulator.run``, the comparison with the float64
-reference) runs at a tiny size. Then the timed path is broken
-underneath, once for each fault the cells can have, and ``correct``
-has to come out false; and the control (the reference with bfloat16
-force arithmetic in the program's place, ``chip_control.py``, which
-runs it at a cell's own size on the chip) has to fail the limit.
+window through ``Simulator.run`` with the outputs its traffic asks for,
+the comparison with the float64 reference and the outputs' checks)
+runs at a tiny size. Then the timed path is broken underneath, once
+for each fault the cells can have, and ``correct`` has to come out
+false; and the control (the reference with bfloat16 force arithmetic
+in the program's place, ``chip_control.py``, which runs it at a cell's
+own size on the chip) has to fail the limit. A cell added as files
+alone (a scenario, a configuration naming it, a traffic mix with every
+output on) runs from a directory of its own.
 """
 
 import json
@@ -28,11 +31,57 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import chip_control  # noqa: E402
 
 N = 256
-CELLS = {"solo": "plummer-262k.solo", "ring": "plummer-1m.ring4"}
+CELLS = {"solo": "plummer-262k.solo", "ring": "plummer-1m.ring4",
+         "monitored": "plummer-262k.monitored",
+         "files_alone": "cube-256.all_outputs"}
+OUTPUT_CHECKS = {"trajectory_frames_off", "trajectory_last_frame_diff",
+                 "checkpoint_faults"}
+# A scenario the benchmark does not have: a uniform cube at rest, with
+# masses drawn from the key.
+TOY_SCENARIO = '''
+import jax
+import jax.numpy as jnp
 
 
-def _cell(kind):
-    cell = registry.cell(CELLS[kind])
+def make(key, cfg):
+    n = cfg["simulation"]["n"]
+    k1, k2 = jax.random.split(key)
+    pos = jax.random.uniform(k1, (n, 3), jnp.float32, -1.0, 1.0) * cfg["side"]
+    m = jax.random.uniform(k2, (n,), jnp.float32, 0.5, 1.5) * cfg["mass"]
+    return pos, jnp.zeros((n, 3), jnp.float32), m
+'''
+
+
+def _files_alone(base):
+    """A copy of the benchmark's files with one cell added as files
+    alone: a scenario module, a configuration that names it and the
+    ``window`` reference, a traffic mix with every output on and the
+    cell's workload file."""
+    shutil.copytree(registry.HERE, base,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (base / "scenarios" / "cube.py").write_text(TOY_SCENARIO)
+    sim = dict(registry.cell(CELLS["solo"])["config_data"]["simulation"],
+               n=N)
+    (base / "configs" / "cube-256.json").write_text(json.dumps({
+        "ics": "cube", "check": "window", "side": 1e12, "mass": 4e27,
+        "n": N, "simulation": sim,
+        "force_evals": {"per_step": 1, "per_run": 1}, "reduced": []}))
+    (base / "traffic" / "all_outputs.json").write_text(json.dumps({
+        "io_pipeline": "auto", "trajectory_every": 5,
+        "checkpoint_every": 10}))
+    (base / "workloads" / f"{CELLS['files_alone']}.json").write_text(
+        json.dumps({"config": "cube-256", "traffic": "all_outputs",
+                    "chips": 1, "why": "a test cell", "block_steps": 5,
+                    "nominal_step_s": 1.0, "sample_targets": 64,
+                    "limits": {"state_gap": 0.01}}))
+    return str(base)
+
+
+def _cell(kind, tmp=None):
+    base = registry.HERE
+    if kind == "files_alone":
+        base = _files_alone(tmp / "chipbench")
+    cell = registry.cell(CELLS[kind], base=base)
     cell["sample_targets"] = 64
     # One window of a few whole blocks at the tiny size.
     cell["nominal_step_s"] = 1.0
@@ -46,16 +95,18 @@ def _over(kind, **extra):
     return over
 
 
-def _run(kind, seed=2**33 + 7, **extra):
+def _run(kind, tmp, seed=2**33 + 7, **extra):
+    cell = _cell(kind, tmp)
     return harness.run_cell(
-        _cell(kind), seed=seed, seconds=2 * _cell(kind)["block_steps"],
-        trace=False, t0=time.perf_counter(), sim_overrides=_over(kind, **extra))
+        cell, seed=seed, seconds=2 * cell["block_steps"], trace=False,
+        t0=time.perf_counter(), sim_overrides=_over(kind, **extra),
+        out_dir=str(tmp / "out"))
 
 
-@pytest.mark.parametrize("kind", ["solo", "ring"])
-def test_rehearsal_is_correct(kind):
-    out = _run(kind)
-    assert out["correct"] is True
+@pytest.mark.parametrize("kind", ["solo", "ring", "monitored", "files_alone"])
+def test_rehearsal_is_correct(kind, tmp_path):
+    out = _run(kind, tmp_path)
+    assert out["correct"] is True, out["checks"]
     assert list(out) == ["correct", "attempted", "failed", "metrics",
                          "device", "window", "checks"]
     assert set(out["metrics"]) == {"step_s", "setup_s"}
@@ -64,6 +115,13 @@ def test_rehearsal_is_correct(kind):
     gap = out["checks"]["state_gap"]
     assert gap["value"] < gap["limit"] / 10
     assert out["attempted"] == out["window"]["steps"]
+    outputs = kind in ("monitored", "files_alone")
+    assert set(out["checks"]) == {"state_gap"} | (
+        OUTPUT_CHECKS if outputs else set())
+    assert all(out["checks"][k]["limit"] == 0 for k in OUTPUT_CHECKS
+               if outputs)
+    # The outputs' directory is gone after the check.
+    assert not os.path.exists(tmp_path / "out" / CELLS[kind])
 
 
 def _wrap_accel(monkeypatch, wrap):
@@ -93,14 +151,32 @@ def _one_answer_altered(inner, p, m):
     return inner(p, m) * scale.astype(p.dtype)[:, None]
 
 
+def _frame_altered(record):
+    """A trajectory writer's ``record`` that moves one value of every
+    frame by one ulp on its way to the file."""
+    import numpy as np
+
+    def altered(self, step, positions):
+        positions = np.array(positions)
+        positions[0, 0] = np.nextafter(positions[0, 0], np.float32(np.inf))
+        return record(self, step, positions)
+
+    return altered
+
+
 @pytest.mark.parametrize("kind,fault", [
     ("solo", "state_unchanged"), ("ring", "state_unchanged"),
     ("solo", "half_the_sources"), ("ring", "half_the_sources"),
     ("solo", "one_answer_altered"), ("ring", "one_answer_altered"),
     ("ring", "no_exchange"),
+    ("monitored", "state_unchanged"), ("monitored", "half_the_sources"),
+    ("monitored", "one_answer_altered"),
+    ("monitored", "trajectory_off"), ("monitored", "checkpoint_left_out"),
+    ("monitored", "frame_altered"),
 ])
-def test_faults_read_not_correct(monkeypatch, kind, fault):
+def test_faults_read_not_correct(monkeypatch, tmp_path, kind, fault):
     import gravity_tpu.simulation as simulation
+    from gravity_tpu.simulation import Simulator
 
     if fault == "state_unchanged":
         monkeypatch.setattr(simulation, "make_step_fn",
@@ -112,11 +188,27 @@ def test_faults_read_not_correct(monkeypatch, kind, fault):
     elif fault == "no_exchange":
         monkeypatch.setattr(jax.lax, "ppermute",
                             lambda x, axis_name, perm: x)
-    out = _run(kind)
+    elif fault == "trajectory_off":
+        # The writer the harness hands over never reaches the run.
+        run = Simulator.run
+        monkeypatch.setattr(
+            Simulator, "run",
+            lambda self, *a, trajectory_writer=None, **k: run(self, *a, **k))
+    elif fault == "checkpoint_left_out":
+        import gravity_tpu.utils.checkpoint as checkpoint
+
+        monkeypatch.setattr(checkpoint, "save_checkpoint",
+                            lambda *a, **k: None)
+    elif fault == "frame_altered":
+        from gravity_tpu.utils.trajectory import TrajectoryWriter
+
+        monkeypatch.setattr(TrajectoryWriter, "record",
+                            _frame_altered(TrajectoryWriter.record))
+    out = _run(kind, tmp_path)
     assert out["correct"] is False, out["checks"]
 
 
-@pytest.mark.parametrize("kind", ["solo", "ring"])
+@pytest.mark.parametrize("kind", ["solo", "ring", "monitored"])
 def test_control_reads_not_correct(kind):
     """The reference with bfloat16 force arithmetic, put in the
     program's place, fails the cell's limit (at this size too)."""
@@ -126,13 +218,13 @@ def test_control_reads_not_correct(kind):
     assert gaps["state_gap"] > cell["limits"]["state_gap"]
 
 
-def test_program_bf16_path_reads_not_correct():
+def test_program_bf16_path_reads_not_correct(tmp_path):
     """The program's own lower-precision path, switched on, fails."""
-    out = _run("solo", dtype="bfloat16")
+    out = _run("solo", tmp_path, dtype="bfloat16")
     assert out["correct"] is False, out["checks"]
 
 
-def test_window_starts_from_the_benchmark_inputs(monkeypatch):
+def test_window_starts_from_the_benchmark_inputs(monkeypatch, tmp_path):
     """A warm block that corrupts the state it ends on cannot feed the
     window: the window starts from the benchmark's initial conditions
     again, so the run stays correct."""
@@ -151,7 +243,7 @@ def test_window_starts_from_the_benchmark_inputs(monkeypatch):
         return out
 
     monkeypatch.setattr(Simulator, "run", run)
-    assert _run("solo")["correct"] is True
+    assert _run("solo", tmp_path)["correct"] is True
     assert len(calls) == 2
 
 
@@ -205,6 +297,6 @@ def test_sample_is_drawn_from_the_seed():
     assert len(set(a.tolist())) == 64
 
 
-def test_result_line_is_json_serialisable():
-    out = _run("solo", seed=5)
+def test_result_line_is_json_serialisable(tmp_path):
+    out = _run("monitored", tmp_path, seed=5)
     json.loads(json.dumps(out))

@@ -15,6 +15,7 @@ import pytest
 
 from chipbench import registry, spans
 from chipbench.harness import Facts
+from chipbench import trace
 from chipbench.trace import WINDOW_END, WINDOW_START, DeviceTrace
 
 HARNESS = ((WINDOW_START, 0, 100), (WINDOW_END, 100, 10))
@@ -42,10 +43,10 @@ def _trace(devices, host=HARNESS, threads=(PROGRAM,)):
 
 
 def test_host_spans_reads_the_program_spans_alone():
-    got = spans.host_spans(_trace([OPS]))
+    got = DeviceTrace(_trace([OPS])).program_spans
     assert [sp[0] for sp in got] == [sp[0] for sp in PROGRAM]
     assert got[0] == ["gravity.run", 5, 98]
-    assert spans.host_spans(_trace([OPS]), "chipbench.")[0] == [
+    assert trace.host_events(_trace([OPS]), trace.HARNESS)[0] == [
         WINDOW_START, 0, 100]
 
 
@@ -58,7 +59,7 @@ def test_innermost_piece_by_piece():
 def test_idle_charged_to_the_innermost_span():
     raw = _trace([OPS])
     dt = DeviceTrace(raw)
-    prog = spans.host_spans(raw)
+    prog = dt.program_spans
     (got,) = spans.idle_charges(dt, prog)
     assert got == {"gravity.run.open": 20, "gravity.run": 10,
                    "gravity.block.wait": 30, "gravity.run.close": 3,
@@ -74,8 +75,7 @@ def test_charges_sum_to_the_idle_share():
     dt = DeviceTrace(raw)
     idle = registry.metric_modules()["device.idle_share"].read(Facts(
         n=1, chips=2, evals=1, window_s=1.0, stats={}, peaks={}, trace=dt))
-    for sp in (spans.host_spans(raw),
-               spans.host_spans(raw) + dt.host_spans):
+    for sp in (dt.program_spans, dt.program_spans + dt.host_spans):
         assert sum(spans.shares(dt, sp).values()) == pytest.approx(
             idle, rel=1e-12)
 
@@ -83,7 +83,7 @@ def test_charges_sum_to_the_idle_share():
 def test_open_and_close_shares_mean_over_devices():
     raw = _trace([OPS, [("fusion.1", 0, 30)] + OPS[1:]])
     dt = DeviceTrace(raw)
-    prog = spans.host_spans(raw)
+    prog = dt.program_spans
     # Device 1 is busy through [10, 30): none of its idle is in open.
     assert spans.share(dt, prog, spans.OPEN) == pytest.approx(
         100 * (20 + 0) / 2 / 110)
@@ -97,7 +97,8 @@ def test_overlapping_threads_charge_the_later_span():
     threads = ((("gravity.run", 0, 100), ("gravity.block.consume", 10, 50)),
                (("gravity.checkpoint", 30, 50),))
     raw = _trace([[("k", 0, 10)]], threads=threads)
-    (got,) = spans.idle_charges(DeviceTrace(raw), spans.host_spans(raw))
+    dt = DeviceTrace(raw)
+    (got,) = spans.idle_charges(dt, dt.program_spans)
     assert got == {"gravity.block.consume": 20, "gravity.checkpoint": 50,
                    "gravity.run": 20, None: 10}
 
@@ -105,10 +106,10 @@ def test_overlapping_threads_charge_the_later_span():
 def test_no_program_spans_reads_none():
     raw = _trace([OPS], threads=())
     dt = DeviceTrace(raw)
-    assert spans.share(dt, spans.host_spans(raw), spans.OPEN) is None
+    assert spans.share(dt, dt.program_spans, spans.OPEN) is None
     assert spans.share(dt, dt.host_spans, spans.CLOSE) is None
-    no_device = _trace([])
-    assert spans.share(DeviceTrace(no_device), spans.host_spans(no_device),
+    no_device = DeviceTrace(_trace([]))
+    assert spans.share(no_device, no_device.program_spans,
                        spans.OPEN) is None
 
 
@@ -118,9 +119,26 @@ def test_recorded_v5e_trace_has_no_program_spans():
     with gzip.open(path, "rt") as fh:
         raw = json.load(fh)
     dt = DeviceTrace(raw)
-    assert spans.host_spans(raw) == []
-    assert spans.share(dt, spans.host_spans(raw), spans.OPEN) is None
+    assert dt.program_spans == []
+    assert spans.share(dt, dt.program_spans, spans.OPEN) is None
     # Charged to the harness's spans alone, the idle still sums to
     # device.idle_share as that run printed it.
     assert sum(spans.shares(dt, dt.host_spans).values()) == pytest.approx(
         0.024447610602007508, rel=1e-9)
+
+
+@pytest.mark.parametrize("metric,want", [
+    ("driver.open_idle_share", 100 * 20 / 110),
+    ("driver.close_idle_share", 100 * (3 + 5) / 110),
+    # The consume [60, 80) and a checkpoint [80, 90) inside the wait.
+    ("driver.consume_idle_share", 100 * (20 + 10) / 110),
+])
+def test_driver_idle_metrics_read_the_program_spans(metric, want):
+    threads = (PROGRAM + (("gravity.block.consume", 60, 20),),
+               (("gravity.checkpoint", 80, 10),))
+    mod = registry.metric_modules()[metric]
+    f = Facts(n=1, chips=1, evals=1, window_s=1.0, stats={}, peaks={},
+              trace=DeviceTrace(_trace([OPS], threads=threads)))
+    assert mod.read(f) == pytest.approx(want, rel=1e-12)
+    f.trace = DeviceTrace(_trace([OPS], threads=()))
+    assert mod.read(f) is None

@@ -56,18 +56,22 @@ def test_busy_is_the_clipped_union_of_ops():
 
 
 def test_idle_share_and_breakdown_labels():
-    tr = _trace([[("fusion.1", 0, 40), ("_nbody_kernel", 60, 40)]],
+    tr = _trace([[("fusion.1", 0, 40), ("_nbody_kernel", 60, 40),
+                  ("copy.1", 108, 12)]],
                 host=((WINDOW_START, 0, 100), (WINDOW_END, 100, 20),
-                      ("chipbench.inner", 40, 10)))
+                      ("chipbench.inner", 40, 10),
+                      ("gravity.run.final_state", 100, 6)))
     mods = registry.metric_modules()
     f = _facts(tr)
     assert mods["device.idle_share"].read(f) == pytest.approx(
-        100 * (1 - 80 / 120))
+        100 * (1 - 92 / 120))
     bd = f.trace.breakdown()
     assert bd["device_ops"][0][1] == pytest.approx(40e-9)
+    # A gap is named by the innermost span over it, the program's
+    # included: the one opened last.
     labels = [g[0] for g in bd["idle_gaps"]]
     assert labels == ["idle in chipbench.inner",
-                      f"idle in {WINDOW_END}"]
+                      "idle in gravity.run.final_state"]
 
 
 def test_roofline_counts_the_algorithm_not_the_kernel():
@@ -124,14 +128,20 @@ def test_recorded_v5e_trace_reads_as_the_chip_run_printed():
     mods = registry.metric_modules()
     got = {name: mods[name].read(f)
            for name in registry.per_layer_for("plummer-262k.solo")}
+    # The block loop's idle shares read None: the fixture predates the
+    # program's spans.
     assert got == pytest.approx({
         "forces_roofline": 1.890912109227137,
         "step_mfu": 1.8880974458464963,
         "device.idle_share": 0.024447610602007508,
         "driver.host_gap_share": 0.00028606042140165834,
-        "driver.window_compiles": 0}, rel=1e-12)
+        "driver.window_compiles": 0,
+        "driver.open_idle_share": None,
+        "driver.close_idle_share": None}, rel=1e-12)
     assert tr.mean_busy_s() == pytest.approx(29.922397174, rel=1e-12)
     assert mods["ring.exposed_comm_share"].read(f) is None
+    assert mods["driver.consume_idle_share"].read(f) is None
+    assert tr.program_spans == []
     ops = dict(tr.breakdown()["device_ops"])
     assert ops["%pallas_accelerations_vs.7"] == pytest.approx(29.50223907)
 
