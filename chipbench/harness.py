@@ -23,7 +23,10 @@
    count their steps from the step before a checkpoint's (``first``),
    as a stretch of a longer run: the first block of each ends on a
    checkpoint, so the window holds one and the first save is warm.
-5. Compares the window's final state with the plain reference the
+5. With ``--trace 1``, counts the work of the window's force
+   evaluations by the module the configuration names (``work/``), for
+   the per-layer metrics to read.
+6. Compares the window's final state with the plain reference the
    configuration names (``reference/``) at bodies drawn from the seed,
    and the outputs with the final state and with each other; prints
    each number compared beside its limit as the last lines of stderr,
@@ -75,7 +78,10 @@ def window_steps(seconds: float, block: int, nominal_step_s: float) -> int:
 
 @dataclasses.dataclass
 class Facts:
-    """What a per-layer metric module may read of a run."""
+    """What a per-layer metric module may read of a run. ``flops`` and
+    ``bytes`` are the work of the window's force evaluations as the
+    configuration's ``work/<name>.py`` counts it (per evaluation, times
+    ``evals``); None where it was not counted."""
 
     n: int
     chips: int
@@ -85,6 +91,21 @@ class Facts:
     peaks: dict
     compiles: int = 0
     trace: object = None
+    flops: float | None = None
+    bytes: float | None = None
+
+
+def work_line(per_eval: dict, peaks: dict) -> dict:
+    """The work of one force evaluation as the result line reports it,
+    with the bound that holds at the published peaks."""
+    line = {"flops_per_eval": per_eval["flops"],
+            "bytes_per_eval": per_eval["bytes"],
+            "note": per_eval["bound_note"]}
+    if peaks:
+        line["bound"] = ("compute" if per_eval["flops"] / peaks["peak_flops"]
+                         >= per_eval["bytes"] / peaks["hbm_bytes_per_s"]
+                         else "memory")
+    return line
 
 
 class _CompileCount:
@@ -275,6 +296,9 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
 
     cfg, traffic = cell["config_data"], cell["traffic_data"]
     base = cell["base"]
+    # The configuration names the work of its force evaluation; without
+    # it no roofline can be read, so a run refuses to start.
+    per_eval = registry.module("work", cfg["work"], base=base).per_eval
     sim_kw = dict(cfg["simulation"], **(sim_overrides or {}))
     n, block = sim_kw["n"], cell["block_steps"]
     steps = window_steps(seconds, block, cell["nominal_step_s"])
@@ -345,6 +369,11 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     xS, vS = np.asarray(final.positions), np.asarray(final.velocities)
     del sim, final
     gc.collect()
+    evals = (steps * cfg["force_evals"]["per_step"]
+             + cfg["force_evals"]["per_run"])
+    # The work the per-layer metrics read, counted after the window.
+    work = (per_eval(dict(cfg, simulation=sim_kw), x0, masses) if trace
+            else None)
 
     # --- the check, after the window and with the program's state freed ---
     t_c0 = time.perf_counter()
@@ -366,8 +395,6 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
     checks.update({k: {"value": v, "limit": 0} for k, v in out_gaps.items()})
     correct = all(c["value"] <= c["limit"] for c in checks.values())
 
-    evals = (steps * cfg["force_evals"]["per_step"]
-             + cfg["force_evals"]["per_run"])
     out = {"correct": bool(correct), "attempted": steps, "failed": 0}
     if not trace:
         out["metrics"] = {
@@ -379,7 +406,9 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
         dtrace = DeviceTrace(load_xplane(newest_xplane(trace_dir)))
         facts = Facts(n=n, chips=cell["chips"], evals=evals,
                       window_s=window_s, stats=stats, peaks=peaks or {},
-                      compiles=compiles.n, trace=dtrace)
+                      compiles=compiles.n, trace=dtrace,
+                      flops=work["flops"] * evals,
+                      bytes=work["bytes"] * evals)
         modules = registry.metric_modules()
         out["metrics"] = {}
         for name in registry.per_layer_for(cell["name"]):
@@ -400,6 +429,8 @@ def run_cell(cell: dict, *, seed: int, seconds: float, trace: bool,
                      "dv_median": gaps["dv_median"],
                      "worst_body": int(idx[gaps["worst_target"]])
                      if gaps["worst_target"] >= 0 else None}
+    if work is not None:
+        out["window"]["work"] = work_line(work, peaks or {})
     out["checks"] = checks
     return out
 

@@ -2,12 +2,19 @@
 
 - ``configs/<config>.json``: one deployment (scenario, sizes, solver,
   sharding) with its source, ``assumed`` and ``reduced``; its ``"ics"``
-  names the scenario and its ``"check"`` the reference;
+  names the scenario, its ``"check"`` the reference and its ``"work"``
+  the count of a force evaluation's work (required: a configuration
+  without it is refused);
 - ``scenarios/<ics>.py``: one scenario's initial conditions, a module
   with ``make(key, cfg) -> (positions, velocities, masses)``;
 - ``reference/<check>.py``: one plain reference, a module with
   ``integrate_targets(x0, v0, m, idx, *, steps, ...)`` whose other
   keyword parameters are keys of the configuration's ``simulation``;
+- ``work/<work>.py``: the work of one force evaluation as the algorithm
+  needs it, whatever kernel does it, a module with
+  ``per_eval(cfg, x0, masses) -> {"flops", "bytes", "bound_note"}``;
+  the harness counts it after the window of a traced run and hands the
+  metrics the window's total (``Facts.flops``, ``Facts.bytes``);
 - ``traffic/<traffic>.json``: one mix, read by the harness's one loop,
   with the program's outputs it switches on;
 - ``workloads/<cell>.json``: one cell: its config, traffic, chips, why,
@@ -18,7 +25,9 @@
   ``read(facts) -> float | None``;
 - ``peaks.json``: published peaks keyed by JAX's ``device_kind``.
 
-A later PR adds a cell, a mix or a metric by adding files alone.
+A later PR adds a cell, a mix or a metric by adding files alone: a new
+deployment is a configuration, and, where its physics is new, a
+scenario, a reference and a work module.
 """
 
 from __future__ import annotations

@@ -19,6 +19,11 @@ WINDOW_START = "chipbench.window.run"
 WINDOW_END = "chipbench.window.fence"
 HARNESS = "chipbench."
 PROGRAM = "gravity."
+# The force kernels' ops: the Pallas kernels (ops/pallas_forces.py
+# _nbody_kernel, ops/pallas_forces_mxu.py, ops/pallas_nlist.py) run as
+# Mosaic custom calls.
+FORCE_KERNEL = (r"_nbody_kernel", r"nbody", r"pallas", r"tpu_custom_call",
+                r"^custom-call")
 # Device planes as the TPU runtime names them, and the line of one
 # event per executed HLO op on each.
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")

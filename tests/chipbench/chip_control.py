@@ -8,8 +8,8 @@ For each seed, one JSON line per reading, all in one process (the cell
 compiles once):
 
 - ``control_ref_bf16`` (``--ref-bf16``): the reference put in the program's place with
-  its force evaluation in bfloat16 (the configuration's ``"check"``,
-  ``reference/window.py`` for every cell so far), from the
+  its pair arithmetic in bfloat16 (the configuration's ``"check"``:
+  ``reference/window.py`` or ``reference/cutoff.py``), from the
   seed's initial conditions, compared with the float64 reference the
   way a run compares the program;
 - ``program`` (``--program``): one run of the cell as the driver makes

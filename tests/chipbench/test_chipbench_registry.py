@@ -51,6 +51,20 @@ def test_cell_found_by_name(bench, cell):
         "scenarios", c["config_data"]["ics"]).make)
     assert callable(registry.module(
         "reference", c["config_data"]["check"]).integrate_targets)
+    # The work of its force evaluation, by name.
+    assert callable(registry.module("work", c["config_data"]["work"]).per_eval)
+
+
+@pytest.mark.parametrize("config", sorted(
+    fn[:-5] for fn in os.listdir(os.path.join(registry.HERE, "configs"))))
+def test_every_configuration_names_its_parts(config):
+    """Each configuration on disk, with a cell or waiting for one, names
+    a scenario, a reference and a work module that exist."""
+    c = registry._load("configs", config)
+    assert c["simulation"]["n"] == c["n"]
+    assert callable(registry.module("scenarios", c["ics"]).make)
+    assert callable(registry.module("reference", c["check"]).integrate_targets)
+    assert callable(registry.module("work", c["work"]).per_eval)
 
 
 def test_metric_modules_match_benchmark(bench):
@@ -66,6 +80,7 @@ def test_metric_modules_match_benchmark(bench):
     assert set(mods) == {m["name"] for m in bench["per_layer"]}
     assert registry.per_layer_for("plummer-262k.solo") == [
         "forces_roofline", "step_mfu", "device.idle_share",
+        "device.outside_kernel_share",
         "driver.host_gap_share", "driver.window_compiles",
         "driver.open_idle_share", "driver.close_idle_share"]
     assert "driver.consume_idle_share" in registry.per_layer_for(

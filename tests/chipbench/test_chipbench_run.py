@@ -54,16 +54,17 @@ def make(key, cfg):
 
 def _files_alone(base):
     """A copy of the benchmark's files with one cell added as files
-    alone: a scenario module, a configuration that names it and the
-    ``window`` reference, a traffic mix with every output on and the
-    cell's workload file."""
+    alone: a scenario module, a configuration that names it, the
+    ``window`` reference and the ``direct`` work, a traffic mix with
+    every output on and the cell's workload file."""
     shutil.copytree(registry.HERE, base,
                     ignore=shutil.ignore_patterns("__pycache__"))
-    (base / "scenarios" / "cube.py").write_text(TOY_SCENARIO)
+    (base / "scenarios" / "toy_cube.py").write_text(TOY_SCENARIO)
     sim = dict(registry.cell(CELLS["solo"])["config_data"]["simulation"],
                n=N)
     (base / "configs" / "cube-256.json").write_text(json.dumps({
-        "ics": "cube", "check": "window", "side": 1e12, "mass": 4e27,
+        "ics": "toy_cube", "check": "window", "work": "direct",
+        "side": 1e12, "mass": 4e27,
         "n": N, "simulation": sim,
         "force_evals": {"per_step": 1, "per_run": 1}, "reduced": []}))
     (base / "traffic" / "all_outputs.json").write_text(json.dumps({
@@ -255,6 +256,67 @@ def test_active_set_holds_targets_and_their_neighbours():
     v0[3, 1] = 1.0  # 2 * 1 * T = 10 more reach
     act = ref.active_set(x0, v0, np.array([0]), eps=1.0, window_s=5.0)
     assert act.tolist() == [0, 1, 3]
+
+
+# A work module the benchmark does not have: fixed numbers.
+TOY_WORK = '''
+def per_eval(cfg, x0, masses):
+    return {"flops": 1.5e12 * cfg["toy_work"], "bytes": 4.0e9,
+            "bound_note": "a test count"}
+'''
+
+
+def test_work_named_by_the_configuration_reaches_the_metrics(tmp_path):
+    """A configuration that names a new work module (added as a file
+    alone) has the metrics read that module's count, times the
+    window's evaluations, with no harness edit."""
+    from chipbench.trace import WINDOW_END, WINDOW_START, DeviceTrace
+
+    base = _files_alone(tmp_path / "chipbench")
+    with open(os.path.join(base, "work", "toy_work.py"), "w") as fh:
+        fh.write(TOY_WORK)
+    path = os.path.join(base, "configs", "cube-256.json")
+    with open(path) as fh:
+        cfg = json.load(fh)
+    with open(path, "w") as fh:
+        json.dump(dict(cfg, work="toy_work", toy_work=2.0), fh)
+    cell = dict(registry.cell(CELLS["files_alone"], base=base),
+                sample_targets=64, nominal_step_s=1.0)
+    out = harness.run_cell(
+        cell, seed=11, seconds=2 * cell["block_steps"], trace=True,
+        t0=time.perf_counter(), sim_overrides=_over("files_alone"),
+        trace_dir=str(tmp_path / "trace"), out_dir=str(tmp_path / "out"),
+        peaks={"peak_flops": 1e12, "hbm_bytes_per_s": 1e9})
+    assert out["correct"] is True, out["checks"]
+    assert out["window"]["work"] == {
+        "flops_per_eval": 3e12, "bytes_per_eval": 4e9,
+        "note": "a test count", "bound": "memory"}
+    # What a metric reads of such a run: the count times the evaluations.
+    evals = out["window"]["force_evals"]
+    flops, nbytes = 3e12 * evals, 4e9 * evals
+    tr = DeviceTrace({"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "python", "events": [
+            [WINDOW_START, 0, 2e9, {}], [WINDOW_END, 2e9, 0, {}]]}]},
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+            ["_nbody_kernel", 0, 1e9, {}]]}]}]})
+    f = harness.Facts(n=N, chips=1, evals=evals, window_s=2.0, stats={},
+                      peaks={"peak_flops": 1e12, "hbm_bytes_per_s": 1e9},
+                      trace=tr, flops=flops, bytes=nbytes)
+    mods = registry.metric_modules(base=base)
+    assert mods["forces_roofline"].read(f) == pytest.approx(
+        100 * max(flops / 1e12, nbytes / 1e9) / 1.0)
+    assert mods["step_mfu"].read(f) == pytest.approx(100 * flops / 2e12)
+
+
+def test_a_configuration_without_work_is_refused(tmp_path):
+    cell = _cell("solo")
+    cell["config_data"] = {k: v for k, v in cell["config_data"].items()
+                           if k != "work"}
+    with pytest.raises(KeyError):
+        harness.run_cell(cell, seed=1, seconds=2 * cell["block_steps"],
+                         trace=False, t0=time.perf_counter(),
+                         sim_overrides=_over("solo"),
+                         out_dir=str(tmp_path / "out"))
 
 
 def test_main_refuses_a_device_that_is_not_a_tpu(capsys):

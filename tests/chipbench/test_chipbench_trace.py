@@ -34,10 +34,47 @@ def _trace(devices, host=((WINDOW_START, 0, 100), (WINDOW_END, 100, 10))):
     return {"planes": planes}
 
 
+def _direct_work(n, evals):
+    """(flops, bytes) of ``evals`` all-pairs evaluations, as the harness
+    passes them: ``work/direct.py``'s count per evaluation, times
+    ``evals``."""
+    w = registry.module("work", "direct").per_eval({}, None, [0.0] * n)
+    return w["flops"] * evals, w["bytes"] * evals
+
+
 def _facts(tr, n=1000, evals=1, chips=1, stats=None, window_s=1.0):
+    flops, nbytes = _direct_work(n, evals)
     return harness.Facts(n=n, chips=chips, evals=evals, window_s=window_s,
                          stats=stats or {}, peaks=PEAKS,
-                         trace=DeviceTrace(tr))
+                         trace=DeviceTrace(tr), flops=flops, bytes=nbytes)
+
+
+def _old_roofline(f, kernel_s):
+    """``forces_roofline`` as it read before the work was named by the
+    configuration: the all-pairs count written into the module."""
+    flops = float(20) * f.n * (f.n - 1) * f.evals
+    min_bytes = (16.0 + 12.0) * f.n * f.evals
+    bound_s = max(flops / f.peaks["peak_flops"],
+                  min_bytes / f.peaks["hbm_bytes_per_s"])
+    return 100.0 * bound_s / (f.chips * kernel_s)
+
+
+def _old_step_mfu(f):
+    work = float(20) * f.n * (f.n - 1) * f.evals
+    window_s = f.trace.window_ns / 1e9
+    return 100.0 * work / (window_s * f.chips * f.peaks["peak_flops"])
+
+
+def _fixture_facts():
+    path = os.path.join(os.path.dirname(__file__), "fixtures",
+                        "v5e_solo_trace.json.gz")
+    with gzip.open(path, "rt") as fh:
+        tr = DeviceTrace(json.load(fh))
+    flops, nbytes = _direct_work(262144, 81)
+    return harness.Facts(n=262144, chips=1, evals=81, window_s=29.9294,
+                         stats={"host_gap_frac": 2.8606042140165834e-06},
+                         peaks=registry.peaks("TPU v5 lite"), trace=tr,
+                         flops=flops, bytes=nbytes)
 
 
 def test_union_and_minus():
@@ -118,16 +155,14 @@ def test_window_compiles_reads_the_harness_count():
 
 
 def test_recorded_v5e_trace_reads_as_the_chip_run_printed():
-    path = os.path.join(os.path.dirname(__file__), "fixtures",
-                        "v5e_solo_trace.json.gz")
-    with gzip.open(path, "rt") as fh:
-        tr = DeviceTrace(json.load(fh))
-    f = harness.Facts(n=262144, chips=1, evals=81, window_s=29.9294,
-                      stats={"host_gap_frac": 2.8606042140165834e-06},
-                      peaks=registry.peaks("TPU v5 lite"), trace=tr)
+    f = _fixture_facts()
+    tr = f.trace
     mods = registry.metric_modules()
     got = {name: mods[name].read(f)
            for name in registry.per_layer_for("plummer-262k.solo")}
+    # Not printed by that run (the metric is newer): see
+    # test_outside_kernel_share.
+    got.pop("device.outside_kernel_share")
     # The block loop's idle shares read None: the fixture predates the
     # program's spans.
     assert got == pytest.approx({
@@ -144,6 +179,58 @@ def test_recorded_v5e_trace_reads_as_the_chip_run_printed():
     assert tr.program_spans == []
     ops = dict(tr.breakdown()["device_ops"])
     assert ops["%pallas_accelerations_vs.7"] == pytest.approx(29.50223907)
+
+
+@pytest.mark.parametrize("kind", ["fixture", "synthetic"])
+def test_direct_work_reads_the_old_formula_bit_for_bit(kind):
+    """The all-pairs count, moved from the two metric modules into
+    ``work/direct.py`` and passed as ``Facts.flops``/``bytes``, gives
+    every reading exactly what the modules computed before."""
+    from chipbench.trace import FORCE_KERNEL, length
+
+    mods = registry.metric_modules()
+    if kind == "fixture":
+        f = _fixture_facts()
+    else:
+        tr = _trace([[("_nbody_kernel", 0, 7.3e5), ("fusion", 8e5, 3e3)]] * 3,
+                    host=((WINDOW_START, 0, 9e5), (WINDOW_END, 9e5, 17)))
+        f = _facts(tr, n=262147, evals=83, chips=3)
+    kernel_s = sum(length(f.trace.intervals(d, FORCE_KERNEL))
+                   for d in range(len(f.trace.devices))) / len(
+                       f.trace.devices) / 1e9
+    assert mods["forces_roofline"].read(f) == _old_roofline(f, kernel_s)
+    assert mods["step_mfu"].read(f) == _old_step_mfu(f)
+
+
+def test_no_work_counted_reads_nothing():
+    f = _facts(_trace([[("_nbody_kernel", 0, 50)]]))
+    f.flops = f.bytes = None
+    mods = registry.metric_modules()
+    assert mods["forces_roofline"].read(f) is None
+    assert mods["step_mfu"].read(f) is None
+
+
+def test_outside_kernel_share():
+    mods = registry.metric_modules()
+    # Kernel [20, 70); other ops [0, 30) and [60, 90): outside the
+    # kernel [0, 20) and [70, 90), 40 of a 110 ns window.
+    ops = [("fusion.1", 0, 30), ("_nbody_kernel", 20, 50), ("sort.2", 60, 30)]
+    f = _facts(_trace([ops, ops]), chips=2)
+    assert mods["device.outside_kernel_share"].read(f) == pytest.approx(
+        100 * 40 / 110)
+    assert mods["device.outside_kernel_share"].read(
+        _facts(_trace([[("fusion.1", 0, 30)]]))) is None
+    # On the recorded solo run: busy time less the kernel's, about 1% of
+    # the window (the integrator's ops between the kernel's calls).
+    from chipbench.trace import FORCE_KERNEL, length
+
+    f = _fixture_facts()
+    kernel_ns = length(f.trace.intervals(0, FORCE_KERNEL))
+    got = mods["device.outside_kernel_share"].read(f)
+    assert got == pytest.approx(
+        100 * (f.trace.busy_ns(0) - kernel_ns) / f.trace.window_ns,
+        rel=1e-9)
+    assert 0.05 < got < 2.0
 
 
 def test_window_needs_the_harness_spans():
