@@ -42,8 +42,8 @@ from gravity_tpu.utils.timing import roofline, sync, warm_sync  # noqa: E402
 
 TILES_I = (256, 512, 1024, 2048)
 TILES_J = (512, 1024, 2048)
-# The symmetric form's square tile; 2048 overflows the v5e's VMEM.
-TILES_SYM = (256, 512, 1024)
+# The symmetric form's square tile.
+TILES_SYM = (512, 1024, 2048)
 FORMULATIONS = ("vpu", "sym", "mxu")
 
 
@@ -108,8 +108,8 @@ def main(argv) -> int:
             )
         ))
     if "sym" in which:
-        # Square tiles: pallas_pairwise_accelerations takes the tile of
-        # area tile_i * tile_j.
+        # Square tiles: pallas_pairwise_accelerations takes a tile as
+        # wide as the wider of tile_i and tile_j.
         variants["sym/fp32"] = ("vpu", "float32",
                                 [(t, t) for t in TILES_SYM],
                                 lambda ti, tj: (

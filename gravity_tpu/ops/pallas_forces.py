@@ -16,9 +16,11 @@ to ``forces[3j]`` (`cuda.cu:47-49`). Two kernels here:
   third law, as the reference's own j>i loop uses it: each unordered
   pair of square (T, T) tiles is evaluated once, over the upper triangle
   of tiles, and serves both bodies: +w_ij·d_ij into body i's row sums,
-  -w_ji·d_ij into body j's column sums. That is ~13 VPU ops per directed
-  pair against the rectangular kernel's ~18. The race the reference had
-  on ``forces[3j]`` cannot happen: the grid runs sequentially on the
+  -w_ji·d_ij into body j's column sums. Its pair pipeline runs on
+  (16, 128) blocks, two vregs, and is VALU-bound: 35 VALU ops and one
+  rsqrt per unordered pair, ~17.5 per directed pair (counted in the
+  v5e compiler's bundles). The race the reference had on
+  ``forces[3j]`` cannot happen: the grid runs sequentially on the
   core's one TensorCore ("arbitrary" semantics), the row sums of tile
   row i are one output block written back once, and the column sums are
   one VMEM-resident block that only the current grid step touches.
@@ -39,6 +41,7 @@ real bodies back.
 from __future__ import annotations
 
 import functools
+import math
 from functools import partial
 
 import jax
@@ -193,71 +196,140 @@ def _sym_tile(nb, p, q):
     return i, j
 
 
-def _nbody_sym_kernel(bi_ref, bjt_ref, acc_ref, col_ref, *, nb, tile,
-                      cutoff, eps, masked):
+# The symmetric kernel's pair pipeline runs on (BLOCK, LANES) blocks:
+# BLOCK bodies of tile i on sublanes by LANES bodies of tile j on lanes,
+# two f32 vregs per value, so that its live set stays in the register
+# file (64 vregs on the v5e) and no value of it is a whole tile. A loop
+# step unrolls GROUP rows of blocks: fewer leave the VALU slots idle at
+# each step's ends, more make the kernel slower to trace and lower
+# (both paid by every process that runs it).
+LANES = 128
+BLOCK = 16
+GROUP = 512
+
+
+def _nbody_sym_kernel(bi_ref, bjt_ref, acc_ref, col_ref, bib_ref, rowp_ref,
+                      *, nb, tile, cutoff, eps, masked):
     """One tile pair (i <= j) of the symmetric all-pairs sum.
 
     ``bi_ref`` (T, 4) and ``bjt_ref`` (4, T) hold x, y, z and G·m of
-    tiles i and j. The first step of each tile row is its diagonal tile,
-    which holds both directions of every pair in it and so adds to the
-    row sums only, overwriting the block (its zeroing). An off-diagonal
-    tile adds +w_ij·d to the row sums of tile i and w_ji·d to the column
-    sums of tile j, which the wrapper subtracts. `masked` as in
-    :func:`_nbody_kernel`; the mask is symmetric, so one zeroed inv_r
-    serves both sides.
+    tiles i and j. Each tile row starts at its diagonal tile, which holds
+    both directions of every pair in it and so adds to the row sums
+    only. An off-diagonal tile adds +w_ij·d to the row sums of tile i
+    and w_ji·d to the column sums of tile j, which the wrapper subtracts.
+    `masked` as in :func:`_nbody_kernel`; the mask is symmetric, so one
+    zeroed inv_r serves both sides.
+
+    The tile is walked one lane chunk of tile j at a time, and in each
+    chunk block by block down tile i. A chunk carries its column partial
+    sums in vregs and reduces them across sublanes once; the diagonal
+    tile computes them too and drops them. The row partial sums add up
+    in ``rowp_ref`` (3, T, LANES) over the whole tile row and are
+    reduced across lanes at its last tile; ``bib_ref`` (4, T, LANES)
+    holds tile i broadcast over the lanes, made at the row's first tile.
     """
     i, j = _sym_tile(nb, pl.program_id(0), pl.program_id(1))
+    dtype = acc_ref.dtype
 
+    # Every column tile j > 0 is first touched by tile row 0, the grid's
+    # first, which sets it; tile 0 is no off-diagonal tile's column.
     @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
     def _():
-        col_ref[...] = jnp.zeros_like(col_ref)
+        col_ref[:, pl.ds(0, tile)] = jnp.zeros((3, tile), dtype)
 
-    bi = bi_ref[...]
-    bjt = bjt_ref[...]
-    dx = bjt[0:1, :] - bi[:, 0:1]  # (T, T)
-    dy = bjt[1:2, :] - bi[:, 1:2]
-    dz = bjt[2:3, :] - bi[:, 2:3]
-    dtype = dx.dtype
-    r2_soft = dx * dx + dy * dy + dz * dz + jnp.asarray(eps * eps, dtype)
-    if masked:
-        valid = r2_soft > jnp.asarray(cutoff * cutoff, dtype)
-        inv_r = jnp.where(
-            valid,
-            jax.lax.rsqrt(jnp.where(valid, r2_soft, jnp.asarray(1.0, dtype))),
-            jnp.asarray(0.0, dtype),
-        )
-    else:
-        inv_r = jax.lax.rsqrt(r2_soft)
-    inv_r2 = inv_r * inv_r
-    # G·m folded in before the powers of inv_r, as in _nbody_kernel:
-    # inv_r**3 alone underflows for r > ~2e12 m; inv_r**2 holds to 1e19 m.
-    w_i = (bjt[3:4, :] * inv_r) * inv_r2  # pull of j on i
-    rows = jnp.concatenate(
-        [jnp.sum(w_i * d, axis=1, keepdims=True) for d in (dx, dy, dz)],
-        axis=1,
-    )  # (T, 3)
+    # The pipeline is unrolled over many blocks, so it is written in lax
+    # ops: jnp's dispatch would dominate the kernel's trace time.
+    add, mul, sub = jax.lax.add, jax.lax.mul, jax.lax.sub
+    full = functools.partial(jax.lax.full, (BLOCK, LANES), dtype=dtype)
+    eps2, cut2, one, zero = (full(v) for v in (eps * eps, cutoff * cutoff,
+                                               1.0, 0.0))
+
+    def pair(bi, bj):
+        """w_ij·d and w_ji·d of one (BLOCK, LANES) block."""
+        d = [sub(bj[k], bi[k]) for k in range(3)]
+        dx, dy, dz = d
+        r2_soft = add(add(add(mul(dx, dx), mul(dy, dy)), mul(dz, dz)), eps2)
+        if masked:
+            valid = jax.lax.gt(r2_soft, cut2)
+            inv_r = jax.lax.select(
+                valid, jax.lax.rsqrt(jax.lax.select(valid, r2_soft, one)),
+                zero)
+        else:
+            inv_r = jax.lax.rsqrt(r2_soft)
+        inv_r2 = mul(inv_r, inv_r)
+        # G·m folded in before the powers of inv_r, as in _nbody_kernel:
+        # inv_r**3 alone underflows for r > ~2e12 m; inv_r**2 holds to
+        # 1e19 m.
+        w_i = mul(mul(bj[3], inv_r), inv_r2)  # pull of j on i
+        w_j = mul(mul(bi[3], inv_r), inv_r2)  # pull of i on j, along -d
+        return [mul(w_i, dk) for dk in d], [mul(w_j, dk) for dk in d]
+
+    group = math.gcd(GROUP, tile)
+
+    def chunk(c, carry):
+        at = pl.ds(pl.multiple_of(c * LANES, LANES), LANES)
+        bj = [jnp.broadcast_to(bjt_ref[k:k + 1, at], (BLOCK, LANES))
+              for k in range(4)]
+
+        def rows(g, cols):
+            # Static offsets from one base, so the compiler sees that the
+            # blocks' loads and stores do not overlap.
+            at_i = pl.ds(pl.multiple_of(g * group, group), group)
+            bib, rowp = bib_ref.at[:, at_i, :], rowp_ref.at[:, at_i, :]
+            for r in range(0, group, BLOCK):
+                f_i, f_j = pair([bib[k, r:r + BLOCK, :] for k in range(4)],
+                                bj)
+                for k in range(3):
+                    rowp[k, r:r + BLOCK, :] = add(rowp[k, r:r + BLOCK, :],
+                                                  f_i[k])
+                cols = [add(a, b) for a, b in zip(cols, f_j)]
+            return cols
+
+        cols = jax.lax.fori_loop(0, tile // group, rows, [zero] * 3)
+        cols = jnp.concatenate(
+            [jnp.sum(v, axis=0, keepdims=True) for v in cols], axis=0)
+
+        @pl.when(i != j)
+        def _():
+            at_j = pl.ds(pl.multiple_of(j * tile + c * LANES, LANES), LANES)
+            col_ref[:, at_j] = jnp.where(
+                i == 0, cols, col_ref[:, at_j] + cols)  # (3, LANES)
+
+        return carry
 
     @pl.when(i == j)
     def _():
-        acc_ref[...] = rows
+        def fill(s, carry):
+            rs = pl.ds(pl.multiple_of(s * 8, 8), 8)
+            b = bi_ref[rs, :]
+            for k in range(4):
+                bib_ref[k, rs, :] = jnp.broadcast_to(b[:, k:k + 1], (8, LANES))
+            for k in range(3):
+                rowp_ref[k, rs, :] = jnp.zeros((8, LANES), dtype)
+            return carry
 
-    @pl.when(i != j)
+        jax.lax.fori_loop(0, tile // 8, fill, 0)
+
+    jax.lax.fori_loop(0, tile // LANES, chunk, 0)
+
+    @pl.when(j == nb - 1)
     def _():
-        acc_ref[...] += rows
-        w_j = (bi[:, 3:4] * inv_r) * inv_r2  # pull of i on j, along -d
-        cols = jnp.concatenate(
-            [jnp.sum(w_j * d, axis=0, keepdims=True) for d in (dx, dy, dz)],
-            axis=0,
-        )  # (3, T)
-        at = pl.ds(pl.multiple_of(j * tile, 128), tile)
-        col_ref[:, at] += cols
+        def put(s, carry):
+            rs = pl.ds(pl.multiple_of(s * 8, 8), 8)
+            acc_ref[rs, :] = jnp.concatenate(
+                [jnp.sum(rowp_ref[k, rs, :], axis=1, keepdims=True)
+                 for k in range(3)], axis=1)
+            return carry
+
+        jax.lax.fori_loop(0, tile // 8, put, 0)
 
 
 # VMEM (v5e: 128 MiB per TensorCore) the symmetric kernel may claim: the
 # column accumulator, one (3, N) block in (4, 128) tiles (16 B/body), and
-# the tile's working set, mostly the compiler's spill slots for the (T, T)
-# intermediates (between 24 and 32 MiB at T = 1024, by compiling for the
-# v5e). 4,456,448 bodies: every run autotune sends to the direct sum.
+# the tile's working set: the (T, 4) and (T, 3) blocks, double-buffered
+# and lane-padded, and the two (·, T, 128) scratch arrays, 11.0 MiB at
+# T = 2048 by compiling for the v5e. 4,456,448 bodies: every run
+# autotune sends to the direct sum.
 SYM_VMEM_BYTES = 100 * 2**20
 SYM_TILE_VMEM_BYTES = 32 * 2**20
 SYM_MAX_N = (SYM_VMEM_BYTES - SYM_TILE_VMEM_BYTES) // 16
@@ -268,13 +340,15 @@ def symmetric_tile(n: int, tile_i: int = TILE_I,
     """The square tile of the symmetric kernel for ``n`` bodies, or None
     where ``n`` takes the rectangular kernel (above :data:`SYM_MAX_N`).
 
-    The tile has the area of ``tile_i`` × ``tile_j`` (the defaults give
-    1024), at least 128 lanes, and at most half of ``n`` rounded up, so
-    the grid has an even number of tile rows, at least two.
+    The tile is as wide as the wider of ``tile_i`` and ``tile_j`` (the
+    defaults give 2048, of 512, 1024 and 2048 the fastest per evaluation
+    at 262,144 bodies on a v5e), at least 128 lanes, and at most half of
+    ``n`` rounded up, so the grid has an even number of tile rows, at
+    least two.
     """
     if n > SYM_MAX_N:
         return None
-    tile = _round_up(int(round((tile_i * tile_j) ** 0.5)), 128)
+    tile = _round_up(max(tile_i, tile_j), 128)
     return min(tile, _round_up(-(-n // 2), 128))
 
 
@@ -347,6 +421,10 @@ def pallas_pairwise_accelerations(
         out_shape=[
             jax.ShapeDtypeStruct((npad, 3), dtype),
             jax.ShapeDtypeStruct((3, npad), dtype),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((4, tile, LANES), dtype),
+            pltpu.VMEM((3, tile, LANES), dtype),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),

@@ -113,18 +113,20 @@ def test_padding_is_exact(key):
 
 @pytest.mark.parametrize("eps", [0.0, 1e10], ids=["masked", "mask_free"])
 @pytest.mark.parametrize(
-    "n", [100, 512, 1000], ids=["one_tile", "four_tiles", "ragged"]
+    "n, tile", [(100, 128), (512, 128), (1000, 128), (1000, 256)],
+    ids=["one_tile", "four_tiles", "ragged", "ragged_two_chunks"],
 )
-def test_symmetric_matches_dense_and_rectangular(key, n, eps):
-    """The symmetric all-pairs kernel (square 128 tiles from tile_i=32,
-    tile_j=128) == the dense sum == the rectangular kernel, on both the
-    masked path (eps = 0: two coincident bodies in different tiles give
-    each other zero, no NaN) and the mask-free one (eps > cutoff)."""
-    assert symmetric_tile(n, 32, 128) == 128
+def test_symmetric_matches_dense_and_rectangular(key, n, tile, eps):
+    """The symmetric all-pairs kernel (square tiles of 128, one lane
+    chunk of 16 blocks, or of 256, two chunks of 32) == the dense sum ==
+    the rectangular kernel, on both the masked path (eps = 0: two
+    coincident bodies in different tiles give each other zero, no NaN)
+    and the mask-free one (eps > cutoff)."""
+    assert symmetric_tile(n, 32, tile) == tile
     pos, masses = _random_system(key, n)
-    pos = pos.at[n - 1].set(pos[0])  # coincident, a tile apart for n > 128
+    pos = pos.at[n - 1].set(pos[0])  # coincident, a tile apart for n > tile
     got = pallas_pairwise_accelerations(
-        pos, masses, eps=eps, tile_i=32, tile_j=128, interpret=True
+        pos, masses, eps=eps, tile_i=32, tile_j=tile, interpret=True
     )
     rect = pallas_accelerations_vs(
         pos, pos, masses, eps=eps, tile_i=32, tile_j=128, interpret=True
@@ -135,6 +137,35 @@ def test_symmetric_matches_dense_and_rectangular(key, n, eps):
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-5, atol=1e-12
         )
+
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of ``jaxpr``, those of its sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_eqns(sub)
+
+
+def test_symmetric_kernel_values_fit_the_register_file():
+    """The symmetric kernel runs its pair pipeline on vreg-sized blocks:
+    traced at the default tile (2048 for N = 4,096), no value in the
+    kernel's jaxpr, loop bodies included, has more than 65,536 elements,
+    64 vregs of (8, 128) f32, the v5e's register file. A (T, T) value of
+    the pipeline would have 4,194,304."""
+    pos, masses = _random_system(jax.random.PRNGKey(3), 4096)
+    assert symmetric_tile(4096) == 2048
+    closed = jax.make_jaxpr(
+        lambda p, m: pallas_pairwise_accelerations(p, m, eps=1e9)
+    )(pos, masses)
+    kernels = [eqn for eqn in _jaxpr_eqns(closed.jaxpr)
+               if eqn.primitive.name == "pallas_call"]
+    assert len(kernels) == 1
+    eqns = list(_jaxpr_eqns(kernels[0].params["jaxpr"]))
+    assert any(eqn.primitive.name == "rsqrt" for eqn in eqns)  # in view
+    sizes = [var.aval.size for eqn in eqns for var in eqn.outvars
+             if isinstance(var.aval, jax.core.ShapedArray)]
+    assert max(sizes) <= 64 * 8 * 128
 
 
 @pytest.mark.parametrize("form", ["symmetric", "rectangular"])
@@ -160,11 +191,11 @@ def test_total_momentum_at_fp32_rounding(form):
 
 def test_form_chosen_by_n(key, monkeypatch):
     """N alone picks the form: the benchmark's 262,144 bodies take the
-    symmetric kernel on 1024 tiles (255 of 257 tiles per tile-row pair
+    symmetric kernel on 2048 tiles (127 of 129 tiles per tile-row pair
     serve both bodies); above the VMEM bound the entry is the
     rectangular kernel, and pair_symmetric_share reads 0."""
-    assert symmetric_tile(262_144) == 1024
-    assert pair_symmetric_share(262_144) == pytest.approx(255 / 257)
+    assert symmetric_tile(262_144) == 2048
+    assert pair_symmetric_share(262_144) == pytest.approx(127 / 129)
     assert symmetric_tile(SYM_MAX_N) is not None
     assert symmetric_tile(SYM_MAX_N + 1) is None
     assert pair_symmetric_share(SYM_MAX_N + 1) == 0.0

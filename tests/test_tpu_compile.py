@@ -64,13 +64,13 @@ def test_vpu_direct_sum_compiles(one_chip):
 def test_symmetric_direct_sum_compiles(one_chip, n):
     """The all-pairs entry's symmetric kernel at the flagship size and at
     the largest N it takes, where its column accumulator fills the VMEM
-    it claims."""
+    it claims, both on the default 2048 tile."""
     from gravity_tpu.ops.pallas_forces import (
         pallas_pairwise_accelerations,
         symmetric_tile,
     )
 
-    assert symmetric_tile(n) == 1024
+    assert symmetric_tile(n) == 2048
     text = _compiled_text(
         partial(pallas_pairwise_accelerations, eps=1e9, interpret=False),
         one_chip, (n, 3), (n,),
